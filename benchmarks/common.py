@@ -39,8 +39,6 @@ def psnr(a, b) -> float:
 def flops_of(fn, *args) -> float:
     """Per-device HLO FLOPs of a jitted callable (cost analysis)."""
     c = jax.jit(fn).lower(*args).compile().cost_analysis()
-    if isinstance(c, (list, tuple)):   # older jax: one dict per device
-        c = c[0] if c else {}
     return float(c.get("flops", 0.0))
 
 

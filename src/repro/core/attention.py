@@ -83,10 +83,13 @@ def masked_block_attention(q, k, v, m_c, m_s, o_reuse, *, block_q, block_kv,
 
 
 def _gather_blocks(x_blocks: jax.Array, ids: jax.Array) -> jax.Array:
-    """Gather block rows: x_blocks (..., T, b, d), ids (..., C) -> (..., C, b, d)."""
-    idx = ids[..., None, None]
-    idx = jnp.broadcast_to(idx, (*ids.shape, *x_blocks.shape[-2:]))
-    return jnp.take_along_axis(x_blocks, idx, axis=-3)
+    """Gather block rows: x_blocks (..., T, b, d), ids (..., C) -> (..., C, b, d).
+
+    The index keeps size-1 trailing dims, so each id copies a whole
+    (b, d) block.  Broadcasting it to (..., C, b, d) would instead gather
+    element by element through an int32 index as large as the output,
+    which on a TPU is orders of magnitude slower."""
+    return jnp.take_along_axis(x_blocks, ids[..., None, None], axis=-3)
 
 
 def _gather_row_blocks(x_blocks: jax.Array, ids: jax.Array) -> jax.Array:

@@ -196,9 +196,11 @@ class PallasBackend:
 class MeshBackend:
     """Mesh-sharded dispatch: the inner backend runs per shard under a
     ``shard_map`` over the (data, seq) engine mesh, exchanging only the
-    plan-live KV blocks (``distributed/plan_shard.py``).  GEMM-Q/GEMM-O
-    delegate to the inner backend unchanged — their sharding is GSPMD's
-    job via the state specs; only attention needs explicit collectives."""
+    plan-live KV blocks (``distributed/plan_shard.py``).  Only attention
+    needs explicit collectives.  XLA GEMM-Q/GEMM-O are left to GSPMD via
+    the state specs; Pallas ones run per data shard under a ``shard_map``
+    (weights replicated, batch on ``data``), because GSPMD cannot
+    partition a compiled TPU kernel."""
 
     def __init__(self, inner, cfg):
         self.inner = inner
@@ -206,8 +208,26 @@ class MeshBackend:
         self.name = f"mesh-{inner.name}"
         self.compact_q = inner.compact_q
 
+    def _per_data_shard(self, fn, x, w, plan, *rest):
+        """``fn(x, w, plan, *rest)`` per data shard: every operand but the
+        weights ``w`` leads with the batch axis."""
+        if self.inner.name != "pallas":
+            return fn(x, w, plan, *rest)
+        from jax.experimental.shard_map import shard_map
+        from jax.sharding import PartitionSpec as P
+        from repro.launch.mesh import make_engine_mesh
+        batch = P("data")
+        specs = (batch, P(), jax.tree.map(lambda _: batch, plan),
+                 *(batch for _ in rest))
+        return shard_map(
+            fn, mesh=make_engine_mesh(self.cfg.mesh_dp, self.cfg.mesh_sp),
+            in_specs=specs, out_specs=batch, check_rep=False)(
+                x, w, plan, *rest)
+
     def gemm_q(self, x, w, plan, *, block):
-        return self.inner.gemm_q(x, w, plan, block=block)
+        return self._per_data_shard(
+            lambda x, w, plan: self.inner.gemm_q(x, w, plan, block=block),
+            x, w, plan)
 
     def attention(self, q, k, v, o_reuse, plan: DispatchPlan,
                   spec: SparseAttentionSpec, *, scale: Optional[float] = None,
@@ -217,7 +237,10 @@ class MeshBackend:
                               spec, scale=scale, compact_q=compact_q)
 
     def gemm_o(self, o_tok, w, plan, bias, *, block, spec=None):
-        return self.inner.gemm_o(o_tok, w, plan, bias, block=block, spec=spec)
+        return self._per_data_shard(
+            lambda o, w, plan, bias: self.inner.gemm_o(
+                o, w, plan, bias, block=block, spec=spec),
+            o_tok, w, plan, bias)
 
 
 _XLA = XlaBackend()

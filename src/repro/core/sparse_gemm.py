@@ -38,8 +38,8 @@ __all__ = [
 
 
 def _gather_rows(xb: jax.Array, ids: jax.Array) -> jax.Array:
-    idx = jnp.broadcast_to(ids[..., None, None], (*ids.shape, *xb.shape[-2:]))
-    return jnp.take_along_axis(xb, idx, axis=-3)
+    # Size-1 trailing index dims: whole-block copies (attention._gather_blocks).
+    return jnp.take_along_axis(xb, ids[..., None, None], axis=-3)
 
 
 def gemm_q_from_plan(
@@ -144,8 +144,8 @@ def gemm_o_from_plan(
     t = n // block
     d_out = w.shape[-1]
     ob = o_heads.reshape(*o_heads.shape[:-3], t, block, h, dh)
-    idx = jnp.broadcast_to(ids[..., None, None, None], (*ids.shape, block, h, dh))
-    og = jnp.take_along_axis(ob, idx, axis=-4)                  # (..., cap, block, H, dh)
+    og = jnp.take_along_axis(ob, ids[..., None, None, None],
+                             axis=-4)                           # (..., cap, block, H, dh)
     og = jnp.where(head_mask[..., None, :, None], og, 0)        # mask cached heads
     yg = jnp.einsum("...cbhd,hdf->...cbf", og, w)
     outb = jnp.zeros((*o_heads.shape[:-3], t, block, d_out), yg.dtype)

@@ -22,6 +22,7 @@ materializes the whole trace.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Optional
 
 import jax
@@ -35,7 +36,7 @@ from repro.core.strategy import strategy_key
 from repro.core.symbols import unpack_bits
 from repro.models import dit
 
-__all__ = ["SamplerConfig", "sample", "make_lane_tick",
+__all__ = ["SamplerConfig", "build_sampler", "sample", "make_lane_tick",
            "make_grouped_lane_tick", "step_density", "pair_sparsity"]
 
 
@@ -83,6 +84,57 @@ _SAMPLER_CACHE_SIZE = 32
 _SAMPLER_CACHE = LruCache(_SAMPLER_CACHE_SIZE)
 
 
+def build_sampler(cfg: ArchConfig, ecfg: EngineConfig, scfg: SamplerConfig,
+                  strategies: tuple, batch: int, n_tokens: int,
+                  with_metrics: bool):
+    """The jitted single-scan sampler that :func:`sample` caches and calls:
+
+        run(params, x0, states, text_emb, patch_embed, mode_arr, id_table)
+            -> (x, states, per-step (density, pair_sparsity) or None)
+
+    ``strategies`` is the resolved schedule's static strategy set; the
+    mode array and strategy-id table are traced operands.  ``states`` is
+    donated and returned, so the step loop updates the engine state in
+    the argument's buffer: at flux width that saves two copies of the
+    57 MB-per-block TaylorSeer state."""
+    n_steps = scfg.num_steps
+    dt = 1.0 / n_steps
+
+    def step_fn(mode: str):
+        def f(params, states, xe, te, t, row, i):
+            kw = {}
+            if mode == "update":
+                kw = dict(strategies=strategies, strategy_row=row,
+                          step_idx=i, num_steps=n_steps)
+            return dit.denoise_step(params, cfg, ecfg, states, xe, te, t,
+                                    mode=mode, dtype=scfg.dtype, **kw)
+        return f
+
+    branches = [step_fn("dense"), step_fn("update"), step_fn("dispatch")]
+
+    def body(params, patch_embed, text_emb, carry, xs):
+        x, states = carry
+        i, mode, row = xs
+        t = (jnp.full((batch,), i, jnp.float32) * dt).astype(scfg.dtype)
+        xe = (x @ patch_embed).astype(scfg.dtype)
+        v, states = jax.lax.switch(mode, branches, params, states, xe,
+                                   text_emb, t, row, i)
+        x = x + v.astype(x.dtype) * dt
+        ys = ((_density_device(states, ecfg, n_tokens),
+               _pair_sparsity_device(states, ecfg, n_tokens))
+              if with_metrics else None)
+        return (x, states), ys
+
+    def run(params, x0, states, text_emb, patch_embed, mode_arr, id_table):
+        steps = jnp.arange(n_steps, dtype=jnp.int32)
+        (x, states), ys = jax.lax.scan(
+            lambda c, xs: body(params, patch_embed, text_emb, c, xs),
+            (x0, states), (steps, mode_arr, id_table))
+        return x, states, ys
+
+    return jax.jit(run, donate_argnums=2)
+
+
 def sample(params, cfg: ArchConfig, ecfg: EngineConfig, *,
            text_emb: jax.Array, x0: jax.Array, scfg: SamplerConfig = SamplerConfig(),
            patch_embed: Optional[jax.Array] = None,
@@ -104,9 +156,10 @@ def sample(params, cfg: ArchConfig, ecfg: EngineConfig, *,
     denoised latents (B, N_v, patch_dim).  ``trace`` (a list) receives one
     ``{step, kind, density, pair_sparsity}`` dict per step; ``stats`` (a
     dict) receives ``executables`` (compiled-executable count for this
-    call — exactly 1), ``schedule`` (the resolved schedule) and the
-    ``sampler_cache`` / ``schedule_cache`` hit/miss/eviction counters of
-    the two LRU-bounded serving memos.
+    call — exactly 1), ``lower`` (a thunk that lowers the sampler at this
+    call's arguments, to inspect the compiled program), ``schedule`` (the
+    resolved schedule) and the ``sampler_cache`` / ``schedule_cache``
+    hit/miss/eviction counters of the two LRU-bounded serving memos.
     """
     b, nv, pd = x0.shape
     n_tokens = nv + text_emb.shape[1]
@@ -119,42 +172,6 @@ def sample(params, cfg: ArchConfig, ecfg: EngineConfig, *,
                              layer_strategies=layer_strategies,
                              force_dense=force_dense)
     with_metrics = trace is not None
-    dt = 1.0 / n_steps
-
-    def build():
-        def step_fn(mode: str):
-            def f(params, states, xe, te, t, row, i):
-                kw = {}
-                if mode == "update":
-                    kw = dict(strategies=sched.strategies, strategy_row=row,
-                              step_idx=i, num_steps=n_steps)
-                return dit.denoise_step(params, cfg, ecfg, states, xe, te, t,
-                                        mode=mode, dtype=scfg.dtype, **kw)
-            return f
-
-        branches = [step_fn("dense"), step_fn("update"), step_fn("dispatch")]
-
-        def body(params, patch_embed, text_emb, carry, xs):
-            x, states = carry
-            i, mode, row = xs
-            t = (jnp.full((b,), i, jnp.float32) * dt).astype(scfg.dtype)
-            xe = (x @ patch_embed).astype(scfg.dtype)
-            v, states = jax.lax.switch(mode, branches, params, states, xe,
-                                       text_emb, t, row, i)
-            x = x + v.astype(x.dtype) * dt
-            ys = ((_density_device(states, ecfg, n_tokens),
-                   _pair_sparsity_device(states, ecfg, n_tokens))
-                  if with_metrics else None)
-            return (x, states), ys
-
-        def run(params, x0, states, text_emb, patch_embed, mode_arr, id_table):
-            steps = jnp.arange(n_steps, dtype=jnp.int32)
-            (x, states), ys = jax.lax.scan(
-                lambda c, xs: body(params, patch_embed, text_emb, c, xs),
-                (x0, states), (steps, mode_arr, id_table))
-            return x, ys
-
-        return jax.jit(run)
 
     key = (cfg, ecfg, scfg, n_steps, with_metrics, b, nv, pd,
            text_emb.shape[1], x0.dtype, text_emb.dtype, patch_embed.dtype,
@@ -166,13 +183,22 @@ def sample(params, cfg: ArchConfig, ecfg: EngineConfig, *,
         # still HITS this cache; ad-hoc strategies key by id() and pin
         # their strategies tuple alive next to the compiled fn so the id
         # can never alias a recycled object.
-        entry = _SAMPLER_CACHE.put(key, (build(), sched.strategies))
+        fn = build_sampler(cfg, ecfg, scfg, sched.strategies, b, n_tokens,
+                           with_metrics)
+        entry = _SAMPLER_CACHE.put(key, (fn, sched.strategies))
     fn = entry[0]
-    x, ys = fn(params, x0, states, text_emb, patch_embed, sched.mode,
-               sched.strategy_ids)
+    args = (params, x0, states, text_emb, patch_embed, sched.mode,
+            sched.strategy_ids)
+    x, _, ys = fn(*args)
     if stats is not None:
         cache_size = getattr(fn, "_cache_size", None)
         stats["executables"] = int(cache_size()) if cache_size else -1
+        # Abstract arguments: the thunk pins no device buffer, and the
+        # donated ``states`` are gone by now.
+        stats["lower"] = functools.partial(fn.lower, *jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(
+                a.shape, a.dtype, sharding=getattr(a, "sharding", None)),
+            args))
         stats["schedule"] = sched
         stats["sampler_cache"] = _SAMPLER_CACHE.stats()
         stats["schedule_cache"] = schedule_cache_stats()

@@ -324,8 +324,7 @@ def mesh_attention(inner, cfg, q, k, v, o_reuse, plan, spec, *,
         send = sq(si).reshape(b_l, h, p_ * pair_cap)
 
         def gather(blocks, ids):
-            idx = jnp.broadcast_to(ids[..., None, None], (*ids.shape, bk, dh))
-            return jnp.take_along_axis(blocks, idx, axis=2)
+            return jnp.take_along_axis(blocks, ids[..., None, None], axis=2)
 
         def a2a(x):
             x = x.reshape(b_l, h, p_, pair_cap, bk, dh)

@@ -52,7 +52,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
 
 __all__ = [
     "flashomni_attention_csr",
@@ -179,7 +178,7 @@ def flashomni_attention_csr(
         out_shape=jax.ShapeDtypeStruct(o_reuse.shape, o_reuse.dtype),
         # NB: alias indices count the scalar-prefetch operands too.
         input_output_aliases={7: 0},                        # o_reuse -> out
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
         ),
         interpret=interpret,
@@ -324,7 +323,7 @@ def flashomni_attention_csr_bucketed(
         out_shape=jax.ShapeDtypeStruct(o_pad.shape, o_pad.dtype),
         # NB: alias indices count the scalar-prefetch operands too.
         input_output_aliases={12: 0},                       # o_pad -> out
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -441,7 +440,7 @@ def flashomni_attention_symbols(
             ],
         ),
         out_shape=jax.ShapeDtypeStruct(o_reuse.shape, o_reuse.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
         ),
         interpret=interpret,

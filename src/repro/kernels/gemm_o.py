@@ -48,7 +48,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
 
 __all__ = ["gemm_o_sparse_kernel", "gemm_o_sparse_bucketed_kernel"]
 
@@ -134,7 +133,7 @@ def gemm_o_sparse_kernel(
         ),
         out_shape=jax.ShapeDtypeStruct(bias.shape, bias.dtype),
         input_output_aliases={5: 0},                         # bias -> out
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary",
                                  "arbitrary"),
         ),
@@ -255,7 +254,7 @@ def gemm_o_sparse_bucketed_kernel(
         out_shape=jax.ShapeDtypeStruct(bias_pad.shape, bias.dtype),
         # NB: alias indices count the scalar-prefetch operands too.
         input_output_aliases={10: 0},                        # bias_pad -> out
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
         ),
         interpret=interpret,

@@ -42,7 +42,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
 
 __all__ = ["gemm_q_sparse_kernel"]
 
@@ -120,7 +119,7 @@ def gemm_q_sparse_kernel(
             scratch_shapes=[pltpu.VMEM((block_rows, block_f), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((b, cr * block_rows, f), x.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary",
                                  "arbitrary"),
         ),

@@ -18,7 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
 
 __all__ = ["taylor_reuse_kernel"]
 
@@ -62,7 +61,7 @@ def taylor_reuse_kernel(
         ),
         out_shape=jax.ShapeDtypeStruct(base.shape, base.dtype),
         input_output_aliases={3: 0},
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

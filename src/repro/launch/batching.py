@@ -149,11 +149,14 @@ def _result(out, trace, arrival, finish):
 
 def run_sequential(params, cfg: ArchConfig, ecfg: EngineConfig, requests,
                    *, scfg_dtype=jnp.float32, patch_embed=None,
-                   collect_traces: bool = True) -> dict:
+                   collect_traces: bool = True,
+                   stats: Optional[dict] = None) -> dict:
     """Baseline server: requests strictly one after another (arrival
     order), each through its own ``pipeline.sample`` call.  Compiled
     samplers are shared across same-config requests via the pipeline's
-    LRU cache; every DISTINCT configuration still pays its own compile."""
+    LRU cache; every DISTINCT configuration still pays its own compile.
+    ``stats`` receives ``pipeline.sample``'s stats of each request in
+    turn (the last request's remain)."""
     if patch_embed is None and requests:
         patch_embed = default_patch_embed(cfg, requests[0].x0.shape[-1])
     results: dict = {}
@@ -168,7 +171,7 @@ def run_sequential(params, cfg: ArchConfig, ecfg: EngineConfig, requests,
                                         dtype=scfg_dtype),
                      patch_embed=patch_embed, trace=trace,
                      schedule=req.schedule,
-                     layer_strategies=req.layer_strategies)
+                     layer_strategies=req.layer_strategies, stats=stats)
         jax.block_until_ready(out)
         results[req.rid] = _result(np.asarray(out), trace, req.arrival,
                                    time.perf_counter() - t0)

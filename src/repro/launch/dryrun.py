@@ -246,10 +246,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, out_dir: Path,
                  "temp_size_in_bytes", "alias_size_in_bytes",
                  "generated_code_size_in_bytes"):
         mem_rec[attr] = getattr(mem, attr, None)
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):       # older jaxlib: one dict per device
-        cost = cost[0] if cost else {}
-    cost = dict(cost or {})
+    cost = dict(compiled.cost_analysis() or {})
     hlo = compiled.as_text()
     coll = collective_bytes(hlo)
 

@@ -26,35 +26,85 @@ Two serving kinds, matching the paper's domain and the LM shape grid:
     requests); latencies are measured against arrival times.
   * ``--kind lm``        — LM prefill + decode loop with KV caches.
 
-On this container both run smoke configs; the jitted step functions are
-the SAME ones the dry-run lowers for the production meshes."""
+By default both run smoke configs on any backend.  ``--full`` serves the
+published widths at the published token count, with bf16 weights and
+compute, through the Pallas kernels compiled for the TPU
+(``interpret=False``): on a machine without a TPU it fails rather than
+fall back.  ``--layers`` cuts the depth to what one chip holds."""
 
 from __future__ import annotations
 
 import argparse
+import dataclasses
+import functools
+import os
 import time
+from pathlib import Path
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec
 
-from repro.configs.registry import get_config, get_smoke
+from repro.configs.registry import arch_shapes, get_config, get_smoke
 from repro.core.engine import EngineConfig
 from repro.core.masks import MaskConfig
 from repro.core.schedule import available_schedules
 from repro.core.strategy import available_strategies
 from repro.launch.batching import (ContinuousBatcher, Request,
                                    run_sequential, run_stacked)
+from repro.models import dit as ditmod
 from repro.models.registry import get_model
 
 
+def enable_compile_cache() -> str:
+    """Keep JAX's persistent compilation cache at a fixed path.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set JAX reads it itself and no
+    other directory is set here.  Otherwise the cache goes to
+    ``<repo>/.jax_cache`` (git-ignored): a fixed path, since the
+    directory is part of what a later run must find again.  Called by
+    entry points only, never at import."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = str(Path(__file__).resolve().parents[3] / ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+def init_weights(cfg, dtype, sharding=None):
+    """Seeded DiT weights made on the device by ONE jitted program whose
+    outputs are already ``dtype``: no f32 copy of the stack and no
+    per-block list is ever held in device memory.  ``sharding`` places
+    the outputs (e.g. replicated over an engine mesh) as they are made."""
+    init = functools.partial(ditmod.init_params, cfg, dtype=dtype)
+    return jax.jit(init, out_shardings=sharding)(jax.random.PRNGKey(0))
+
+
 def serve_diffusion(arch: str, *, smoke: bool = True, num_requests: int = 2,
-                    batch: int = 2, n_vision: int = 96, num_steps: int = 12,
+                    batch: Optional[int] = None,
+                    n_vision: Optional[int] = None, num_steps: int = 12,
                     strategy: str = "flashomni", schedule: str = None,
                     serving: str = "sequential", lanes: int = 4,
                     arrival_interval: float = 0.0, mixed_steps: bool = False,
                     mixed_shapes: bool = False, shape_buckets=None,
-                    mesh: tuple = (1, 1)):
+                    mesh: tuple = (1, 1), layers: Optional[int] = None,
+                    backend: Optional[str] = None,
+                    stats: Optional[dict] = None):
     """Queue-driven diffusion serving (see module docstring for modes).
+
+    ``smoke`` picks the tiny CPU config: f32, CPU-sized tiles (16/16,
+    pool 32), 96 vision tokens, batch 2 and the XLA backend.  Otherwise
+    the published config runs at its published vision length
+    (``arch_shapes``), the ``MaskConfig`` tiles (64/64, pool 128), batch
+    1, bf16 weights and compute, and the Pallas kernels compiled for the
+    TPU.  Batch 1 because the CSR attention kernel prefetches its per-row
+    KV lists into scalar memory (1 MiB on a v5e): at flux length they
+    take 458 KB for batch 1 and overflow at batch 4.  ``layers`` cuts
+    the depth (``n_layers``); ``backend`` overrides the engine backend
+    (e.g. ``"xla"`` as the reference of a Pallas run).  ``stats`` (a
+    dict, sequential serving only) receives :func:`pipeline.sample`'s
+    stats of the last request.
 
     ``schedule`` names a registered SparsitySchedule preset (e.g.
     ``hunyuan-1.5x``, ``step-ramp``); it overrides the per-step mapping of
@@ -72,13 +122,31 @@ def serve_diffusion(arch: str, *, smoke: bool = True, num_requests: int = 2,
     exchanges only plan-live KV blocks.  Needs ``dp·sp`` local devices.
     Returns the per-request result dict from :mod:`repro.launch.batching`.
     """
-    cfg = get_smoke(arch) if smoke else get_config(arch)
-    ecfg = EngineConfig(mask=MaskConfig(
-        tau_q=0.5, tau_kv=0.15, interval=4, order=1, degrade=0.3,
-        block_q=16, block_kv=16, pool=32, warmup_steps=2),
-        strategy=strategy, mesh_dp=mesh[0], mesh_sp=mesh[1])
-    from repro.models import dit as ditmod
-    params = ditmod.init_params(cfg, jax.random.PRNGKey(0))
+    mask = dict(tau_q=0.5, tau_kv=0.15, interval=4, order=1, degrade=0.3,
+                warmup_steps=2)
+    if smoke:
+        cfg = get_smoke(arch)
+        n_vision = n_vision or 96
+        batch = batch or 2
+        mask.update(block_q=16, block_kv=16, pool=32)
+        dtype, engine = jnp.float32, dict(backend=backend or "xla")
+    else:
+        cfg = get_config(arch)
+        n_vision = n_vision or arch_shapes(cfg)[0].seq_len - cfg.n_text_tokens
+        batch = batch or 1
+        dtype = jnp.bfloat16
+        engine = dict(backend=backend or "pallas", interpret=False)
+    if layers is not None:
+        cfg = dataclasses.replace(cfg, n_layers=layers)
+    ecfg = EngineConfig(mask=MaskConfig(**mask), strategy=strategy,
+                        mesh_dp=mesh[0], mesh_sp=mesh[1], **engine)
+    sharding = None
+    if mesh != (1, 1):
+        # Weights replicate over the engine mesh as they are made; left
+        # unplaced they would all land on the first device.
+        from repro.launch.mesh import make_engine_mesh
+        sharding = NamedSharding(make_engine_mesh(*mesh), PartitionSpec())
+    params = init_weights(cfg, dtype, sharding)
     label = schedule or strategy
 
     requests = []
@@ -106,6 +174,7 @@ def serve_diffusion(arch: str, *, smoke: bool = True, num_requests: int = 2,
         if shape_buckets is None and mixed_shapes:
             shape_buckets = (n_vision,)
         batcher = ContinuousBatcher(params, cfg, ecfg, lanes=lanes,
+                                    scfg_dtype=dtype,
                                     shape_buckets=shape_buckets)
         batcher.submit_all(requests)
         results = batcher.run()
@@ -121,9 +190,10 @@ def serve_diffusion(arch: str, *, smoke: bool = True, num_requests: int = 2,
             fold = "=" if orig == canon else "->"
             print(f"[serve]   x0 {orig[0]} {fold} lane {canon[0]}")
     elif serving == "stacked":
-        results = run_stacked(params, cfg, ecfg, requests)
+        results = run_stacked(params, cfg, ecfg, requests, scfg_dtype=dtype)
     elif serving == "sequential":
-        results = run_sequential(params, cfg, ecfg, requests)
+        results = run_sequential(params, cfg, ecfg, requests,
+                                 scfg_dtype=dtype, stats=stats)
     else:
         raise ValueError(f"unknown serving mode {serving!r}; expected "
                          "sequential | stacked | continuous")
@@ -177,7 +247,12 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--kind", default="lm", choices=["lm", "diffusion"])
-    ap.add_argument("--full", action="store_true")
+    ap.add_argument("--full", action="store_true",
+                    help="published widths and token count, bf16, Pallas "
+                         "kernels compiled for the TPU (fails off-TPU)")
+    ap.add_argument("--layers", type=int, default=None,
+                    help="cut the diffusion model's depth to this many "
+                         "blocks (e.g. what one chip holds)")
     ap.add_argument("--strategy", default="flashomni",
                     choices=available_strategies(),
                     help="sparse-symbol producer for --kind diffusion")
@@ -215,6 +290,7 @@ def main():
         assert len(mesh) == 2 and mesh[0] >= 1 and mesh[1] >= 1
     except (ValueError, AssertionError):
         ap.error(f"--mesh expects 'dp,sp' positive ints, got {args.mesh!r}")
+    enable_compile_cache()
     if args.kind == "diffusion":
         serve_diffusion(args.arch, smoke=not args.full,
                         strategy=args.strategy, schedule=args.schedule,
@@ -225,7 +301,7 @@ def main():
                         mixed_shapes=args.mixed_shapes,
                         shape_buckets=(tuple(args.shape_buckets)
                                        if args.shape_buckets else None),
-                        mesh=mesh)
+                        mesh=mesh, layers=args.layers)
     else:
         serve_lm(args.arch, smoke=not args.full)
 

@@ -145,10 +145,8 @@ def build_dit_step(cfg: ArchConfig, shape: ShapeSpec, mesh: Mesh,
                 inputs["t"], mode=mode)
         return v, new_states
 
-    model_shape = jax.eval_shape(lambda: ditmod.init_params(cfg, jax.random.PRNGKey(0)))
-    model_shape = jax.tree.map(
-        lambda s: jax.ShapeDtypeStruct(
-            s.shape, jnp.bfloat16 if s.dtype == jnp.float32 else s.dtype), model_shape)
+    model_shape = jax.eval_shape(lambda: ditmod.init_params(
+        cfg, jax.random.PRNGKey(0), jnp.bfloat16))
     n_tok = shape.seq_len
     states_shape = jax.eval_shape(
         lambda: ditmod.init_engine_states(cfg, ecfg, shape.global_batch, n_tok))

@@ -19,7 +19,7 @@ schedule's active strategy set inside the block body; nothing unrolls).
 
 from __future__ import annotations
 
-from typing import Any, Optional
+from typing import Any
 
 import jax
 import jax.numpy as jnp
@@ -41,22 +41,21 @@ def timestep_embedding(t: jax.Array, dim: int, max_period: float = 10000.0):
     return jnp.concatenate([jnp.cos(ang), jnp.sin(ang)], axis=-1)
 
 
-def _init_block(cfg: ArchConfig, key, stack: Optional[int]):
+def _init_block(cfg: ArchConfig, key):
     d, h, hd = cfg.d_model, cfg.n_heads, cfg.hd
     ks = jax.random.split(key, 7)
-    sh = lambda *dims: dims if stack is None else (stack, *dims)
     s = d ** -0.5
     return {
-        "wq": jax.random.normal(ks[0], sh(d, h * hd)) * s,
-        "wk": jax.random.normal(ks[1], sh(d, h * hd)) * s,
-        "wv": jax.random.normal(ks[2], sh(d, h * hd)) * s,
-        "wo": jax.random.normal(ks[3], sh(h * hd, d)) * s,
-        "q_scale": jnp.ones(sh(hd)),
-        "k_scale": jnp.ones(sh(hd)),
-        "mlp_wi": jax.random.normal(ks[4], sh(d, cfg.d_ff)) * s,
-        "mlp_wo": jax.random.normal(ks[5], sh(cfg.d_ff, d)) * (cfg.d_ff ** -0.5),
-        "adaln": jax.random.normal(ks[6], sh(d, 6 * d)) * 0.02,
-        "adaln_b": jnp.zeros(sh(6 * d)),
+        "wq": jax.random.normal(ks[0], (d, h * hd)) * s,
+        "wk": jax.random.normal(ks[1], (d, h * hd)) * s,
+        "wv": jax.random.normal(ks[2], (d, h * hd)) * s,
+        "wo": jax.random.normal(ks[3], (h * hd, d)) * s,
+        "q_scale": jnp.ones((hd,)),
+        "k_scale": jnp.ones((hd,)),
+        "mlp_wi": jax.random.normal(ks[4], (d, cfg.d_ff)) * s,
+        "mlp_wo": jax.random.normal(ks[5], (cfg.d_ff, d)) * (cfg.d_ff ** -0.5),
+        "adaln": jax.random.normal(ks[6], (d, 6 * d)) * 0.02,
+        "adaln_b": jnp.zeros((6 * d,)),
     }
 
 
@@ -69,19 +68,28 @@ def _block_specs():
             "adaln": (*n, "fsdp", None), "adaln_b": (*n, None)}
 
 
-def init_params(cfg: ArchConfig, key) -> Any:
+def init_params(cfg: ArchConfig, key, dtype=jnp.float32) -> Any:
+    """Seeded random weights, every leaf stored in ``dtype``.
+
+    Layer ``i`` draws from ``fold_in(kb, i)``; the blocks are drawn under
+    ``vmap`` so the (L, ...) stack is built in one piece, never as a list
+    of per-block arrays.  Called under ``jax.jit`` with a bf16 ``dtype``
+    (``launch/serve.init_weights``) the f32 draws stay inside the fused
+    program and only the bf16 stack reaches device memory.
+    """
     kb, kt, kf, kp = jax.random.split(key, 4)
     d = cfg.d_model
-    blocks = [_init_block(cfg, jax.random.fold_in(kb, i), None)
-              for i in range(cfg.n_layers)]
-    return {
-        "blocks": jax.tree.map(lambda *x: jnp.stack(x), *blocks),
+    layer_keys = jax.vmap(lambda i: jax.random.fold_in(kb, i))(
+        jnp.arange(cfg.n_layers))
+    params = {
+        "blocks": jax.vmap(lambda k: _init_block(cfg, k))(layer_keys),
         "t_mlp1": jax.random.normal(kt, (256, d)) * 0.02,
         "t_mlp2": jax.random.normal(jax.random.fold_in(kt, 1), (d, d)) * 0.02,
         "final_mod": jax.random.normal(kf, (d, 2 * d)) * 0.02,
         "final_proj": jax.random.normal(kp, (d, cfg.patch_dim)) * 0.02,
         "final_norm": jnp.ones((d,)),
     }
+    return jax.tree.map(lambda x: x.astype(dtype), params)
 
 
 def param_specs(cfg: ArchConfig) -> Any:

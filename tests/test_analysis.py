@@ -364,8 +364,6 @@ def test_cost_model_matches_xla_on_dense_gemm_and_attention():
 
     def xla_flops(fn, *args):
         c = jax.jit(fn).lower(*args).compile().cost_analysis()
-        if isinstance(c, (list, tuple)):
-            c = c[0] if c else {}
         return float(c.get("flops", 0.0))
 
     gemm = lambda a, b: jnp.einsum("bnd,df->bnf", a, b)
