@@ -494,38 +494,49 @@ def update_layer(
     :class:`~repro.core.strategy.StrategyContext`.
     """
     b, n, dm = x.shape
-    q, k = _qk(params, x, heads, freqs)
-    v = _project_heads(x, params.wv, heads)
-    o = dense_attention(q, k, v)                               # (B,H,N,dh)
+    with jax.named_scope("fo.qkv"):
+        q, k = _qk(params, x, heads, freqs)
+        v = _project_heads(x, params.wv, heads)
+    with jax.named_scope("fo.attention"):
+        o = dense_attention(q, k, v)                           # (B,H,N,dh)
     ctx = StrategyContext(cfg=cfg, n_text=n_text, n_tokens=n,
                           layer_idx=layer_idx, step_idx=step_idx,
                           num_steps=num_steps)
-    if strategies is not None:
-        sid = jnp.zeros((), jnp.int32) if strategy_id is None else strategy_id
-        syms = emit_switch(sid, q, k, ctx, strategies)
-    else:
-        strat = get_strategy(cfg.strategy if strategy is None else strategy)
-        syms = strat.emit(q, k, ctx)
+    with jax.named_scope("fo.symbols"):
+        if strategies is not None:
+            sid = (jnp.zeros((), jnp.int32) if strategy_id is None
+                   else strategy_id)
+            syms = emit_switch(sid, q, k, ctx, strategies)
+        else:
+            strat = get_strategy(cfg.strategy if strategy is None
+                                 else strategy)
+            syms = strat.emit(q, k, ctx)
     s_c, s_s, m_c, m_s = syms.s_c, syms.s_s, syms.m_c, syms.m_s
 
-    o_tok = o.transpose(0, 2, 1, 3)                            # (B,N,H,dh)
-    dh = o_tok.shape[-1]
-    wo_h = params.wo.reshape(heads, dh, dm)
-    out = jnp.einsum("bnhd,hdf->bnf", o_tok, wo_h)
+    with jax.named_scope("fo.o_proj"):
+        o_tok = o.transpose(0, 2, 1, 3)                        # (B,N,H,dh)
+        dh = o_tok.shape[-1]
+        wo_h = params.wo.reshape(heads, dh, dm)
+        out = jnp.einsum("bnhd,hdf->bnf", o_tok, wo_h)
 
-    m_ch = jnp.swapaxes(m_c, -1, -2)                           # (B, T, H)
-    if cfg.cache_mode == "bias":
-        bias = sparse_gemm.gemm_o_update_bias(o_tok, wo_h, m_ch, block=cfg.mask.pool)
-        taylor = taylorseer.update(state.taylor, bias.astype(cfg.cache_dtype))
-    else:
-        taylor = taylorseer.update(state.taylor, o.astype(cfg.cache_dtype))
+    with jax.named_scope("fo.cache"):
+        m_ch = jnp.swapaxes(m_c, -1, -2)                       # (B, T, H)
+        if cfg.cache_mode == "bias":
+            bias = sparse_gemm.gemm_o_update_bias(o_tok, wo_h, m_ch,
+                                                  block=cfg.mask.pool)
+            taylor = taylorseer.update(state.taylor,
+                                       bias.astype(cfg.cache_dtype))
+        else:
+            taylor = taylorseer.update(state.taylor,
+                                       o.astype(cfg.cache_dtype))
     # Compile-once index plan: ALL index decoding for the coming Dispatch
     # steps happens here, amortized over the next interval−1 steps.  Rows
     # are ranked for the capacity truncation by the strategy's clamp
     # scores (column mass), summed over the heads where the row is live.
-    row_score = jnp.sum(
-        jnp.where(m_c, syms.q_scores.astype(jnp.float32), 0.0), axis=-2)
-    plan = build_dispatch_plan(m_c, m_s, cfg, n, row_score=row_score)
+    with jax.named_scope("fo.plan"):
+        row_score = jnp.sum(
+            jnp.where(m_c, syms.q_scores.astype(jnp.float32), 0.0), axis=-2)
+        plan = build_dispatch_plan(m_c, m_s, cfg, n, row_score=row_score)
     new_state = LayerState(s_c=s_c, s_s=s_s, taylor=taylor,
                            k_since=jnp.zeros((), jnp.int32), plan=plan)
     return out, new_state
@@ -556,56 +567,63 @@ def dispatch_layer(
     plan_stored = state.plan if plan is None else plan
     plan = plan_stored.widen()    # int16 id fields -> int32 for kernels/RoPE
     backend = get_backend(cfg)
-    k_since = state.k_since + 1
     spec_c = cfg.caps(n)                                        # block granularity caps
+    with jax.named_scope("fo.cache"):
+        k_since = state.k_since + 1
+        forecast = taylorseer.forecast(state.taylor, k_since, m.interval)
 
     # --- GEMM-Q: skip row blocks cached in every head (Obs. 2). ---
-    if cfg.use_gemm_q:
-        q_flat = backend.gemm_q(x, params.wq, plan, block=m.pool)
-        compact = backend.compact_q                             # (B, Cr·pool, H·dh)
-    else:
-        q_flat = jnp.einsum("bnd,df->bnf", x, params.wq)
-        compact = False
-    n_q = q_flat.shape[1]
-    qh = q_flat.reshape(b, n_q, heads, -1).transpose(0, 2, 1, 3)
-    qh = rms_norm(qh, params.q_scale)
-    k_h = rms_norm(_project_heads(x, params.wk, heads), params.k_scale)
-    if freqs is not None:
-        q_freqs = freqs
-        if compact:
-            # Compact rows are gathered: RoPE phases follow the ORIGINAL
-            # token positions of the gathered live rows.
-            pos = (plan.row_ids[..., :, None] * m.pool
-                   + jnp.arange(m.pool)).reshape(b, n_q)        # (B, Cr·pool)
-            q_freqs = freqs[pos][:, None]                       # (B,1,n_q,dh/2)
-        qh, k_h = apply_rope(qh, q_freqs), apply_rope(k_h, freqs)
-    v_h = _project_heads(x, params.wv, heads)
+    with jax.named_scope("fo.qkv"):
+        if cfg.use_gemm_q:
+            q_flat = backend.gemm_q(x, params.wq, plan, block=m.pool)
+            compact = backend.compact_q                         # (B, Cr·pool, H·dh)
+        else:
+            q_flat = jnp.einsum("bnd,df->bnf", x, params.wq)
+            compact = False
+        n_q = q_flat.shape[1]
+        qh = q_flat.reshape(b, n_q, heads, -1).transpose(0, 2, 1, 3)
+        qh = rms_norm(qh, params.q_scale)
+        k_h = rms_norm(_project_heads(x, params.wk, heads), params.k_scale)
+        if freqs is not None:
+            q_freqs = freqs
+            if compact:
+                # Compact rows are gathered: RoPE phases follow the
+                # ORIGINAL token positions of the gathered live rows.
+                pos = (plan.row_ids[..., :, None] * m.pool
+                       + jnp.arange(m.pool)).reshape(b, n_q)    # (B, Cr·pool)
+                q_freqs = freqs[pos][:, None]                   # (B,1,n_q,dh/2)
+            qh, k_h = apply_rope(qh, q_freqs), apply_rope(k_h, freqs)
+        v_h = _project_heads(x, params.wv, heads)
 
     # --- Attention: backend sparse path over the frozen plan. ---
     dh = qh.shape[-1]
-    if cfg.cache_mode == "bias":
-        o_reuse = jnp.zeros((b, heads, n, dh), qh.dtype)
-    else:
-        o_reuse = taylorseer.forecast(state.taylor, k_since, m.interval).astype(qh.dtype)
-    o = backend.attention(qh, k_h, v_h, o_reuse, plan, spec_c,
-                          compact_q=compact)
+    with jax.named_scope("fo.attention"):
+        if cfg.cache_mode == "bias":
+            o_reuse = jnp.zeros((b, heads, n, dh), qh.dtype)
+        else:
+            o_reuse = forecast.astype(qh.dtype)
+        o = backend.attention(qh, k_h, v_h, o_reuse, plan, spec_c,
+                              compact_q=compact)
 
     # --- GEMM-O: live heads + forecast bias (Obs. 3, Eq. 4). ---
-    o_tok = o.transpose(0, 2, 1, 3)
-    wo_h = params.wo.reshape(heads, dh, dm)
-    if cfg.cache_mode == "bias":
-        bias_f = taylorseer.forecast(state.taylor, k_since, m.interval).astype(x.dtype)
-        if cfg.use_gemm_o:
-            out = backend.gemm_o(o_tok, wo_h, plan, bias_f, block=m.pool,
-                                 spec=spec_c)
+    with jax.named_scope("fo.o_proj"):
+        o_tok = o.transpose(0, 2, 1, 3)
+        wo_h = params.wo.reshape(heads, dh, dm)
+        if cfg.cache_mode == "bias":
+            bias_f = forecast.astype(x.dtype)
+            if cfg.use_gemm_o:
+                out = backend.gemm_o(o_tok, wo_h, plan, bias_f, block=m.pool,
+                                     spec=spec_c)
+            else:
+                # Dense GEMM over (zero-filled) cached heads + forecast
+                # bias — numerically identical, no FLOP saving (fidelity
+                # fallback).
+                m_tok = jnp.repeat(plan.m_ch, m.pool, axis=-2)[..., :n, :]
+                out = jnp.einsum("bnhd,hdf->bnf",
+                                 jnp.where(m_tok[..., None], o_tok, 0),
+                                 wo_h) + bias_f
         else:
-            # Dense GEMM over (zero-filled) cached heads + forecast bias —
-            # numerically identical, no FLOP saving (fidelity fallback).
-            m_tok = jnp.repeat(plan.m_ch, m.pool, axis=-2)[..., :n, :]
-            out = jnp.einsum("bnhd,hdf->bnf",
-                             jnp.where(m_tok[..., None], o_tok, 0), wo_h) + bias_f
-    else:
-        out = jnp.einsum("bnhd,hdf->bnf", o_tok, wo_h)
+            out = jnp.einsum("bnhd,hdf->bnf", o_tok, wo_h)
     new_state = LayerState(s_c=state.s_c, s_s=state.s_s, taylor=state.taylor,
                            k_since=k_since, plan=plan_stored)
     return out, new_state

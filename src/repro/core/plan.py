@@ -98,6 +98,7 @@ __all__ = [
     "bucket_grid_slots",
     "bucket_layout",
     "gmo_layout",
+    "live_work",
     "occupancy_histogram",
     "OCC_BINS",
 ]
@@ -589,6 +590,47 @@ def build_dispatch_plan(m_c: jax.Array, m_s: jax.Array, cfg, n_tokens: int,
         jax.debug.callback(
             lambda p: hook_validate(p, cfg, n_tokens), plan)
     return plan
+
+
+def live_work(plan: DispatchPlan) -> dict[str, tuple[jax.Array, int]]:
+    """Live work against launched grid slots of the three Dispatch kernels.
+
+    ``{"gemm_q_rows", "csr_tiles", "gemm_o_heads"} -> (live, launched)``,
+    each summed over every leading axis of the plan (layers, batch,
+    heads).  ``live`` is an int32 device scalar; ``launched`` is static,
+    read from the plan's shapes, which carry its geometry:
+
+      * ``gemm_q_rows``: compact row blocks GEMM-Q computes (Σ ``row_cnt``)
+        against its ``Cr`` row slots per sample;
+      * ``csr_tiles``: (q-block, kv-block) tiles CSR attention computes
+        (Σ ``kv_row_cnt`` over live rows) against its grid steps:
+        ``B·H·Cq·Ckv`` uniform, ``B·S`` bucketed, the per-shard
+        ``B·H·P·Cqs·Ckv`` on a plan-sharded mesh;
+      * ``gemm_o_heads``: (row, head) slots GEMM-O reduces (Σ ``head_cnt``)
+        against ``B·Cr·H`` uniform, ``B·S`` bucketed.
+
+    Padding slots (rows past ``q_cnt`` / ``row_cnt``, columns past a row's
+    count) take a grid step and are not live work.
+    """
+    total = lambda a: jnp.sum(a, dtype=jnp.int32)
+    if plan.shd_kv_row_ids is not None:
+        live_rows = (jnp.arange(plan.shd_kv_row_cnt.shape[-1])
+                     < plan.shd_q_cnt[..., None])
+        csr = (total(jnp.where(live_rows, plan.shd_kv_row_cnt, 0)),
+               plan.shd_kv_row_ids.size)
+    elif plan.bkt_kv_cnt is not None:
+        csr = total(plan.bkt_kv_cnt), plan.bkt_kv_ids.size
+    else:
+        live_rows = (jnp.arange(plan.kv_row_cnt.shape[-1])
+                     < plan.q_cnt[..., None])
+        csr = (total(jnp.where(live_rows, plan.kv_row_cnt, 0)),
+               plan.kv_row_ids.size)
+    if plan.gmo_head_cnt is not None:
+        gmo = total(plan.gmo_head_cnt), plan.gmo_head_ids.size
+    else:
+        gmo = total(plan.head_cnt), plan.head_ids.size
+    return {"gemm_q_rows": (total(plan.row_cnt), plan.row_ids.size),
+            "csr_tiles": csr, "gemm_o_heads": gmo}
 
 
 def empty_plan_like(batch: int, heads: int, n_tokens: int, cfg) -> DispatchPlan:

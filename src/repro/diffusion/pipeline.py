@@ -14,9 +14,9 @@ mix, or per-layer deployment tables (enforced by the compile-count test in
 
 The pipeline reports the paper's efficiency accounting per step: density
 (fraction of live attention work, Fig. 7), sparsity (skip/total, Table 1)
-and the attention-FLOP reduction the benchmarks consume.  Metrics
-accumulate on device as scan outputs; one host sync after the loop
-materializes the whole trace.
+and the Dispatch kernels' live work against their launched grid slots.
+Metrics accumulate on device as scan outputs; one host sync after the
+loop materializes the whole trace.
 """
 
 from __future__ import annotations
@@ -27,17 +27,19 @@ from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ArchConfig
 from repro.core.engine import (EngineConfig, merge_lane_states,
                                resolve_schedule, schedule_cache_stats)
 from repro.core.lru import LruCache
+from repro.core.plan import live_work
 from repro.core.strategy import strategy_key
 from repro.core.symbols import unpack_bits
 from repro.models import dit
 
 __all__ = ["SamplerConfig", "build_sampler", "sample", "make_lane_tick",
-           "make_grouped_lane_tick", "step_density", "pair_sparsity"]
+           "make_grouped_lane_tick"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -61,17 +63,6 @@ def _pair_sparsity_device(states, ecfg: EngineConfig, n_tokens: int) -> jax.Arra
     return 1.0 - jnp.mean(live.astype(jnp.float32))
 
 
-def step_density(states, cfg: ArchConfig, ecfg: EngineConfig, n_tokens: int) -> float:
-    """Fig. 7 density: fraction of (q-block, head) work still live."""
-    return float(_density_device(states, ecfg, n_tokens))
-
-
-def pair_sparsity(states, cfg: ArchConfig, ecfg: EngineConfig, n_tokens: int) -> float:
-    """Paper 'Sparsity' metric: skipped (Q_i K_j, P_ij V_j) pairs / total —
-    combines feature caching (dead rows) and block-sparse skipping."""
-    return float(_pair_sparsity_device(states, ecfg, n_tokens))
-
-
 # Compiled single-scan samplers, keyed on every static of the trace (model /
 # engine / sampler configs, shapes, metric mode, schedule strategy
 # identities — stable across calls because resolve_schedule memoizes).  A
@@ -90,7 +81,19 @@ def build_sampler(cfg: ArchConfig, ecfg: EngineConfig, scfg: SamplerConfig,
     """The jitted single-scan sampler that :func:`sample` caches and calls:
 
         run(params, x0, states, text_emb, patch_embed, mode_arr, id_table)
-            -> (x, states, per-step (density, pair_sparsity) or None)
+            -> (x, states, per-step metrics or None)
+
+    The per-step metrics (``with_metrics``) are ``(density, pair_sparsity,
+    live, grid)``: ``live`` / ``grid`` map each Dispatch kernel to the
+    live work and the launched grid slots of the plan the step leaves in
+    the engine state (:func:`repro.core.plan.live_work`, summed over
+    layers) — on a Dispatch step, the frozen plan its kernels ran on.
+
+    Every op of a step sits under the step mode's named scope (``fo.dense``
+    / ``fo.update`` / ``fo.dispatch``) and one block part (``fo.qkv``,
+    ``fo.attention``, ``fo.o_proj``, ``fo.symbols``, ``fo.plan``,
+    ``fo.cache``, ``fo.mlp``, ``fo.io``), so a profile attributes device
+    time by ``op_name``.
 
     ``strategies`` is the resolved schedule's static strategy set; the
     mode array and strategy-id table are traced operands.  ``states`` is
@@ -106,24 +109,31 @@ def build_sampler(cfg: ArchConfig, ecfg: EngineConfig, scfg: SamplerConfig,
             if mode == "update":
                 kw = dict(strategies=strategies, strategy_row=row,
                           step_idx=i, num_steps=n_steps)
-            return dit.denoise_step(params, cfg, ecfg, states, xe, te, t,
-                                    mode=mode, dtype=scfg.dtype, **kw)
+            with jax.named_scope(f"fo.{mode}"):
+                return dit.denoise_step(params, cfg, ecfg, states, xe, te, t,
+                                        mode=mode, dtype=scfg.dtype, **kw)
         return f
 
     branches = [step_fn("dense"), step_fn("update"), step_fn("dispatch")]
 
+    def metrics(states):
+        work = live_work(states.plan)
+        return (_density_device(states, ecfg, n_tokens),
+                _pair_sparsity_device(states, ecfg, n_tokens),
+                {k: live for k, (live, _) in work.items()},
+                {k: jnp.int32(slots) for k, (_, slots) in work.items()})
+
     def body(params, patch_embed, text_emb, carry, xs):
         x, states = carry
         i, mode, row = xs
-        t = (jnp.full((batch,), i, jnp.float32) * dt).astype(scfg.dtype)
-        xe = (x @ patch_embed).astype(scfg.dtype)
+        with jax.named_scope("fo.io"):
+            t = (jnp.full((batch,), i, jnp.float32) * dt).astype(scfg.dtype)
+            xe = (x @ patch_embed).astype(scfg.dtype)
         v, states = jax.lax.switch(mode, branches, params, states, xe,
                                    text_emb, t, row, i)
-        x = x + v.astype(x.dtype) * dt
-        ys = ((_density_device(states, ecfg, n_tokens),
-               _pair_sparsity_device(states, ecfg, n_tokens))
-              if with_metrics else None)
-        return (x, states), ys
+        with jax.named_scope("fo.io"):
+            x = x + v.astype(x.dtype) * dt
+        return (x, states), (metrics(states) if with_metrics else None)
 
     def run(params, x0, states, text_emb, patch_embed, mode_arr, id_table):
         steps = jnp.arange(n_steps, dtype=jnp.int32)
@@ -154,30 +164,41 @@ def sample(params, cfg: ArchConfig, ecfg: EngineConfig, *,
 
     ``patch_embed``: (patch_dim, d_model) stub patchifier.  Returns the
     denoised latents (B, N_v, patch_dim).  ``trace`` (a list) receives one
-    ``{step, kind, density, pair_sparsity}`` dict per step; ``stats`` (a
+    ``{step, kind, density, pair_sparsity, live, grid}`` dict per step
+    (``live`` / ``grid``: live work and launched grid slots per Dispatch
+    kernel, see :func:`build_sampler`); ``stats`` (a
     dict) receives ``executables`` (compiled-executable count for this
     call — exactly 1), ``lower`` (a thunk that lowers the sampler at this
     call's arguments, to inspect the compiled program), ``schedule`` (the
     resolved schedule) and the ``sampler_cache`` / ``schedule_cache``
     hit/miss/eviction counters of the two LRU-bounded serving memos.
+
+    Host spans (``jax.profiler.TraceAnnotation``, free while no profiler
+    runs) name the call's host work on the profile's clock: ``fo.states``,
+    ``fo.schedule``, ``fo.launch`` (argument ``compiled``: the call
+    compiled) and ``fo.metrics``.
     """
     b, nv, pd = x0.shape
     n_tokens = nv + text_emb.shape[1]
     n_steps = scfg.num_steps
-    states = dit.init_engine_states(cfg, ecfg, b, n_tokens)
+    with TraceAnnotation("fo.states"):
+        states = dit.init_engine_states(cfg, ecfg, b, n_tokens)
     if patch_embed is None:
         patch_embed = jax.random.normal(jax.random.PRNGKey(7), (pd, cfg.d_model)) * 0.2
 
-    sched = resolve_schedule(ecfg, n_steps, cfg.n_layers, schedule=schedule,
-                             layer_strategies=layer_strategies,
-                             force_dense=force_dense)
+    with TraceAnnotation("fo.schedule"):
+        sched = resolve_schedule(ecfg, n_steps, cfg.n_layers,
+                                 schedule=schedule,
+                                 layer_strategies=layer_strategies,
+                                 force_dense=force_dense)
     with_metrics = trace is not None
 
     key = (cfg, ecfg, scfg, n_steps, with_metrics, b, nv, pd,
            text_emb.shape[1], x0.dtype, text_emb.dtype, patch_embed.dtype,
            tuple(strategy_key(s) for s in sched.strategies))
     entry = _SAMPLER_CACHE.get(key)
-    if entry is None:
+    missed = entry is None
+    if missed:
         # Registry strategies key by VALUE (strategy_key), so a schedule
         # re-resolved after an LRU eviction of the resolve_schedule memo
         # still HITS this cache; ad-hoc strategies key by id() and pin
@@ -189,9 +210,15 @@ def sample(params, cfg: ArchConfig, ecfg: EngineConfig, *,
     fn = entry[0]
     args = (params, x0, states, text_emb, patch_embed, sched.mode,
             sched.strategy_ids)
-    x, _, ys = fn(*args)
+    cache_size = getattr(fn, "_cache_size", None)
+    size = cache_size() if cache_size else 0
+    with TraceAnnotation("fo.launch") as span:
+        x, _, ys = fn(*args)
+        # Names a compile in the profile: a new sampler, or a new
+        # executable of a cached one (another argument signature).
+        span.set_metadata(compiled=missed or bool(
+            cache_size and cache_size() > size))
     if stats is not None:
-        cache_size = getattr(fn, "_cache_size", None)
         stats["executables"] = int(cache_size()) if cache_size else -1
         # Abstract arguments: the thunk pins no device buffer, and the
         # donated ``states`` are gone by now.
@@ -204,11 +231,15 @@ def sample(params, cfg: ArchConfig, ecfg: EngineConfig, *,
         stats["schedule_cache"] = schedule_cache_stats()
     if with_metrics:
         kinds = sched.kinds()
-        dens, pair_s = jax.device_get(ys)      # ONE host sync for the trace
-        for i in range(n_steps):
-            trace.append({"step": i, "kind": kinds[i],
-                          "density": float(dens[i]),
-                          "pair_sparsity": float(pair_s[i])})
+        with TraceAnnotation("fo.metrics"):
+            # ONE host sync for the trace
+            dens, pair_s, live, grid = jax.device_get(ys)
+            for i in range(n_steps):
+                trace.append({
+                    "step": i, "kind": kinds[i], "density": float(dens[i]),
+                    "pair_sparsity": float(pair_s[i]),
+                    "live": {k: int(v[i]) for k, v in live.items()},
+                    "grid": {k: int(v[i]) for k, v in grid.items()}})
     return x
 
 

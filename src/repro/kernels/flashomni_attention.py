@@ -182,6 +182,7 @@ def flashomni_attention_csr(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
         ),
         interpret=interpret,
+        name="flashomni_csr_attention",
     )(q_ids, q_src_ids, flat_kv, kv_cnt, q, k, v, o_reuse)
 
 
@@ -327,6 +328,7 @@ def flashomni_attention_csr_bucketed(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="flashomni_csr_attention_bucketed",
     )(jnp.asarray(srow), jnp.asarray(jof), jnp.asarray(soff),
       jnp.asarray(slast), bkt_head, bkt_q_write, bkt_q_read,
       bkt_kv_ids, bkt_kv_cnt, q, k, v, o_pad)
@@ -444,4 +446,5 @@ def flashomni_attention_symbols(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
         ),
         interpret=interpret,
+        name="flashomni_attention_symbols",
     )(s_c, s_s, q, k, v, o_reuse)

@@ -138,6 +138,7 @@ def gemm_o_sparse_kernel(
                                  "arbitrary"),
         ),
         interpret=interpret,
+        name="flashomni_gemm_o",
     )(flat_rows, flat_heads, flat_cnt, o_heads, w, bias)
     return out[0] if squeeze else out
 
@@ -258,6 +259,7 @@ def gemm_o_sparse_bucketed_kernel(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
         ),
         interpret=interpret,
+        name="flashomni_gemm_o_bucketed",
     )(jnp.asarray(srow), jnp.asarray(jof), jnp.asarray(soff),
       jnp.asarray(slast), gmo_rows, gmo_src, gmo_head_ids, gmo_head_cnt,
       o_heads, w, bias_pad)

@@ -124,5 +124,6 @@ def gemm_q_sparse_kernel(
                                  "arbitrary"),
         ),
         interpret=interpret,
+        name="flashomni_gemm_q",
     )(row_ids.reshape(-1), row_cnt.reshape(-1).astype(jnp.int32), x, w)
     return out[0] if squeeze else out

@@ -65,4 +65,5 @@ def taylor_reuse_kernel(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="flashomni_taylor_reuse",
     )(ids, coef, derivs, base)

@@ -50,6 +50,7 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ArchConfig
 from repro.core.engine import (EngineConfig, resolve_schedule,
@@ -156,7 +157,9 @@ def run_sequential(params, cfg: ArchConfig, ecfg: EngineConfig, requests,
     samplers are shared across same-config requests via the pipeline's
     LRU cache; every DISTINCT configuration still pays its own compile.
     ``stats`` receives ``pipeline.sample``'s stats of each request in
-    turn (the last request's remain)."""
+    turn (the last request's remain).  Each request runs inside a host
+    span ``fo.request`` (argument ``rid``), with ``fo.wait`` around the
+    wait for the device and ``fo.fetch`` around the copy to the host."""
     if patch_embed is None and requests:
         patch_embed = default_patch_embed(cfg, requests[0].x0.shape[-1])
     results: dict = {}
@@ -166,14 +169,18 @@ def run_sequential(params, cfg: ArchConfig, ecfg: EngineConfig, requests,
         if now < req.arrival:
             time.sleep(req.arrival - now)
         trace: list = [] if collect_traces else None
-        out = sample(params, cfg, ecfg, text_emb=req.text_emb, x0=req.x0,
-                     scfg=SamplerConfig(num_steps=req.num_steps,
-                                        dtype=scfg_dtype),
-                     patch_embed=patch_embed, trace=trace,
-                     schedule=req.schedule,
-                     layer_strategies=req.layer_strategies, stats=stats)
-        jax.block_until_ready(out)
-        results[req.rid] = _result(np.asarray(out), trace, req.arrival,
+        with TraceAnnotation("fo.request", rid=req.rid):
+            out = sample(params, cfg, ecfg, text_emb=req.text_emb, x0=req.x0,
+                         scfg=SamplerConfig(num_steps=req.num_steps,
+                                            dtype=scfg_dtype),
+                         patch_embed=patch_embed, trace=trace,
+                         schedule=req.schedule,
+                         layer_strategies=req.layer_strategies, stats=stats)
+            with TraceAnnotation("fo.wait"):
+                jax.block_until_ready(out)
+            with TraceAnnotation("fo.fetch"):
+                out = np.asarray(out)
+        results[req.rid] = _result(out, trace, req.arrival,
                                    time.perf_counter() - t0)
     return results
 

@@ -182,9 +182,12 @@ def _block(cfg: ArchConfig, ecfg: EngineConfig, p, state, x, t_emb, *, mode: str
            n_text: int, strategy=None, layer_idx=None, strategy_id=None,
            strategies=None, step_idx=None, num_steps=None):
     dtype = x.dtype
-    mod = (jax.nn.silu(t_emb) @ p["adaln"].astype(dtype) + p["adaln_b"].astype(dtype))
-    sh_a, sc_a, g_a, sh_m, sc_m, g_m = jnp.split(mod, 6, axis=-1)
-    xa = _modulate(L.rms_norm(x, jnp.ones((cfg.d_model,)), cfg.norm_eps), sh_a, sc_a)
+    with jax.named_scope("fo.mlp"):
+        mod = (jax.nn.silu(t_emb) @ p["adaln"].astype(dtype)
+               + p["adaln_b"].astype(dtype))
+        sh_a, sc_a, g_a, sh_m, sc_m, g_m = jnp.split(mod, 6, axis=-1)
+        xa = _modulate(L.rms_norm(x, jnp.ones((cfg.d_model,)), cfg.norm_eps),
+                       sh_a, sc_a)
     attn_p = AttnParams(wq=p["wq"].astype(dtype), wk=p["wk"].astype(dtype),
                         wv=p["wv"].astype(dtype), wo=p["wo"].astype(dtype),
                         q_scale=p["q_scale"], k_scale=p["k_scale"])
@@ -199,18 +202,24 @@ def _block(cfg: ArchConfig, ecfg: EngineConfig, p, state, x, t_emb, *, mode: str
         o, new_state = E.dispatch_layer(attn_p, xa, state, ecfg, n_text=n_text,
                                         heads=cfg.n_heads)
     else:  # "dense": engine off (baseline / training)
-        q, k = E._qk(attn_p, xa, cfg.n_heads, None)
-        v = E._project_heads(xa, attn_p.wv, cfg.n_heads)
         from repro.core.attention import dense_attention
-        oh = dense_attention(q, k, v)
-        o = oh.transpose(0, 2, 1, 3).reshape(*xa.shape[:2], -1) @ attn_p.wo
+        with jax.named_scope("fo.qkv"):
+            q, k = E._qk(attn_p, xa, cfg.n_heads, None)
+            v = E._project_heads(xa, attn_p.wv, cfg.n_heads)
+        with jax.named_scope("fo.attention"):
+            oh = dense_attention(q, k, v)
+        with jax.named_scope("fo.o_proj"):
+            o = oh.transpose(0, 2, 1, 3).reshape(*xa.shape[:2], -1) @ attn_p.wo
         new_state = state
     from repro.distributed.ctx import constrain
-    x = constrain(x + g_a[:, None] * o.astype(dtype), "dp", "sp", None)
-    xm = _modulate(L.rms_norm(x, jnp.ones((cfg.d_model,)), cfg.norm_eps), sh_m, sc_m)
-    y = constrain(jax.nn.gelu(xm @ p["mlp_wi"].astype(dtype)), "dp", "sp", "tp")
-    y = constrain(y @ p["mlp_wo"].astype(dtype), "dp", "sp", None)
-    return x + g_m[:, None] * y, new_state
+    with jax.named_scope("fo.mlp"):
+        x = constrain(x + g_a[:, None] * o.astype(dtype), "dp", "sp", None)
+        xm = _modulate(L.rms_norm(x, jnp.ones((cfg.d_model,)), cfg.norm_eps),
+                       sh_m, sc_m)
+        y = constrain(jax.nn.gelu(xm @ p["mlp_wi"].astype(dtype)),
+                      "dp", "sp", "tp")
+        y = constrain(y @ p["mlp_wo"].astype(dtype), "dp", "sp", None)
+        return x + g_m[:, None] * y, new_state
 
 
 def denoise_step(params, cfg: ArchConfig, ecfg: EngineConfig, states: LayerState,
@@ -251,10 +260,14 @@ def denoise_step(params, cfg: ArchConfig, ecfg: EngineConfig, states: LayerState
     b = x_vision.shape[0]
     n_text = text_emb.shape[1]
     from repro.distributed.ctx import constrain
-    x = jnp.concatenate([text_emb.astype(dtype), x_vision.astype(dtype)], axis=1)
-    x = constrain(x, "dp", "sp", None)
-    t_emb = timestep_embedding(t * 1000.0, 256).astype(dtype) @ params["t_mlp1"].astype(dtype)
-    t_emb = (jax.nn.silu(t_emb) @ params["t_mlp2"].astype(dtype)).astype(dtype)
+    with jax.named_scope("fo.io"):
+        x = jnp.concatenate([text_emb.astype(dtype), x_vision.astype(dtype)],
+                            axis=1)
+        x = constrain(x, "dp", "sp", None)
+        t_emb = (timestep_embedding(t * 1000.0, 256).astype(dtype)
+                 @ params["t_mlp1"].astype(dtype))
+        t_emb = (jax.nn.silu(t_emb)
+                 @ params["t_mlp2"].astype(dtype)).astype(dtype)
 
     if layer_strategies is not None:
         if strategies is not None or strategy_row is not None:
@@ -285,10 +298,12 @@ def denoise_step(params, cfg: ArchConfig, ecfg: EngineConfig, states: LayerState
         xs = (*xs, jnp.asarray(strategy_row, jnp.int32))
     from repro.models import layers as L
     x, new_states = L.maybe_scan(body, x, xs, scan=cfg.scan_layers)
-    mod = jax.nn.silu(t_emb) @ params["final_mod"].astype(dtype)
-    sh, sc = jnp.split(mod, 2, axis=-1)
-    x = _modulate(L.rms_norm(x, params["final_norm"], cfg.norm_eps), sh, sc)
-    v = x[:, n_text:] @ params["final_proj"].astype(dtype)
+    with jax.named_scope("fo.io"):
+        mod = jax.nn.silu(t_emb) @ params["final_mod"].astype(dtype)
+        sh, sc = jnp.split(mod, 2, axis=-1)
+        x = _modulate(L.rms_norm(x, params["final_norm"], cfg.norm_eps),
+                      sh, sc)
+        v = x[:, n_text:] @ params["final_proj"].astype(dtype)
     return v, new_states
 
 

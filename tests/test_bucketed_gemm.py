@@ -22,6 +22,7 @@
     from static config, and a mesh forces uniform).
 """
 
+import copy
 import dataclasses
 
 import jax
@@ -256,10 +257,8 @@ def test_validate_table_failure_modes():
         lambda t: t["bucket_model"].update({"max_clamp_frac": 2.0}),
         lambda t: t.update(strategies={"x": {"occ_hist": [-1.0]}}),
     ]:
-        bad = {k: ({kk: dict(vv) if isinstance(vv, dict) else vv
-                    for kk, vv in v.items()} if isinstance(v, dict) else v)
-               for k, v in ok.items()}
-        mutate(bad)
+        bad = copy.deepcopy(ok)      # the loaded table is memoized: never
+        mutate(bad)                  # mutate what later callers read
         with pytest.raises(ValueError):
             validate_table(bad)
 

@@ -112,7 +112,7 @@ def test_gemm_o_compiles_at_flux_width(one_chip):
 def test_full_width_dispatch_step_compiles_with_kernels(one_chip):
     """One published-width block through ``denoise_step(mode="dispatch")``
     with the Pallas backend compiled for the chip, as ``serve --full``
-    runs it."""
+    runs it, with the three kernels under their names."""
     from repro.models import dit
     cfg = dataclasses.replace(ARCH, n_layers=1)
     n_text = cfg.n_text_tokens
@@ -134,4 +134,10 @@ def test_full_width_dispatch_step_compiles_with_kernels(one_chip):
             jax.ShapeDtypeStruct((1, N_TOKENS - n_text, D), jnp.bfloat16),
             jax.ShapeDtypeStruct((1, n_text, D), jnp.bfloat16),
             jax.ShapeDtypeStruct((1,), jnp.bfloat16)))).compile()
-    assert compiled.as_text().count("tpu_custom_call") >= 3
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 3
+    # The kernels keep their names in the compiled module, where the
+    # profile's reduction finds them.
+    for name in ("flashomni_csr_attention", "flashomni_gemm_q",
+                 "flashomni_gemm_o"):
+        assert name in text, name
