@@ -149,12 +149,12 @@ class PallasBackend:
         out = flashomni_attention_csr(
             flat(q), flat(k), flat(v), flat(o_reuse),
             flat(plan.q_ids), flat(plan.kv_row_ids), flat(plan.kv_row_cnt),
-            block_q=spec.block_q, block_kv=spec.block_kv, scale=scale,
-            interpret=self.interpret,
+            flat(plan.q_cnt), block_q=spec.block_q, block_kv=spec.block_kv,
+            scale=scale, interpret=self.interpret,
             q_src_ids=flat(plan.q_slots) if compact_q else None)
         # Degenerate all-cached guard (paper A.1.1 S_q degradation): with
-        # zero live rows the kernel writes garbage through the duplicated
-        # slot-0 id; keep the pure-reuse tensor for those (b, h).
+        # zero live rows the kernel leaves the duplicated slot-0 block
+        # undefined; keep the pure-reuse tensor for those (b, h).
         any_live = (flat(plan.q_cnt) > 0)[:, None, None]
         out = jnp.where(any_live, out, flat(o_reuse))
         return out.reshape(b, h, n, dh)

@@ -98,6 +98,7 @@ __all__ = [
     "bucket_grid_slots",
     "bucket_layout",
     "gmo_layout",
+    "csr_path",
     "live_work",
     "occupancy_histogram",
     "OCC_BINS",
@@ -592,20 +593,48 @@ def build_dispatch_plan(m_c: jax.Array, m_s: jax.Array, cfg, n_tokens: int,
     return plan
 
 
-def live_work(plan: DispatchPlan) -> dict[str, tuple[jax.Array, int]]:
+def csr_path(cfg, n_tokens: int, head_dim: int, dtype) -> str:
+    """The CSR attention kernel a Dispatch step runs at these shapes:
+    ``"bucketed"`` (``kv_buckets`` > 1 on one device), else ``"resident"``
+    or ``"streaming"``, as ``kernels.flashomni_attention.csr_resident``
+    decides on the K/V each call reads: the whole sequence, or on a
+    sequence-sharded mesh the per-shard buffer (union blocks plus one pad
+    block)."""
+    from repro.kernels.flashomni_attention import csr_resident
+    spec = cfg.caps(n_tokens)
+    n_kv = n_tokens
+    if getattr(cfg, "mesh_sp", 1) > 1 and getattr(cfg, "mesh_axis",
+                                                  "seq") == "seq":
+        from repro.distributed.plan_shard import shard_geometry
+        t = -(-n_tokens // spec.block_kv)
+        geom = shard_geometry(spec, t, t, cfg.mesh_sp,
+                              getattr(cfg, "mesh_pair_slack", 1.5))
+        n_kv = (geom.cap_kv + 1) * spec.block_kv
+    elif spec.kv_buckets > 1:
+        return "bucketed"
+    itemsize = jnp.dtype(dtype).itemsize
+    return ("resident" if csr_resident(n_kv, head_dim, itemsize)
+            else "streaming")
+
+
+def live_work(plan: DispatchPlan, resident: bool
+              ) -> dict[str, tuple[jax.Array, jax.Array | int]]:
     """Live work against launched grid slots of the three Dispatch kernels.
 
     ``{"gemm_q_rows", "csr_tiles", "gemm_o_heads"} -> (live, launched)``,
     each summed over every leading axis of the plan (layers, batch,
-    heads).  ``live`` is an int32 device scalar; ``launched`` is static,
-    read from the plan's shapes, which carry its geometry:
+    heads).  ``live`` is an int32 device scalar; ``launched`` is a static
+    int read from the plan's shapes, which carry its geometry, except on
+    the resident walk, where it is ``live``:
 
       * ``gemm_q_rows``: compact row blocks GEMM-Q computes (Σ ``row_cnt``)
         against its ``Cr`` row slots per sample;
       * ``csr_tiles``: (q-block, kv-block) tiles CSR attention computes
-        (Σ ``kv_row_cnt`` over live rows) against its grid steps:
-        ``B·H·Cq·Ckv`` uniform, ``B·S`` bucketed, the per-shard
-        ``B·H·P·Cqs·Ckv`` on a plan-sharded mesh;
+        (Σ ``kv_row_cnt`` over live rows) against the tiles it launches:
+        its grid steps ``B·H·Cq·Ckv`` uniform, ``B·S`` bucketed, the
+        per-shard ``B·H·P·Cqs·Ckv`` on a plan-sharded mesh; with
+        ``resident`` (the uniform kernel's resident walk, see
+        :func:`csr_path`) only the live tiles, so ``launched`` is ``live``;
       * ``gemm_o_heads``: (row, head) slots GEMM-O reduces (Σ ``head_cnt``)
         against ``B·Cr·H`` uniform, ``B·S`` bucketed.
 
@@ -616,15 +645,15 @@ def live_work(plan: DispatchPlan) -> dict[str, tuple[jax.Array, int]]:
     if plan.shd_kv_row_ids is not None:
         live_rows = (jnp.arange(plan.shd_kv_row_cnt.shape[-1])
                      < plan.shd_q_cnt[..., None])
-        csr = (total(jnp.where(live_rows, plan.shd_kv_row_cnt, 0)),
-               plan.shd_kv_row_ids.size)
+        tiles = total(jnp.where(live_rows, plan.shd_kv_row_cnt, 0))
+        csr = tiles, (tiles if resident else plan.shd_kv_row_ids.size)
     elif plan.bkt_kv_cnt is not None:
         csr = total(plan.bkt_kv_cnt), plan.bkt_kv_ids.size
     else:
         live_rows = (jnp.arange(plan.kv_row_cnt.shape[-1])
                      < plan.q_cnt[..., None])
-        csr = (total(jnp.where(live_rows, plan.kv_row_cnt, 0)),
-               plan.kv_row_ids.size)
+        tiles = total(jnp.where(live_rows, plan.kv_row_cnt, 0))
+        csr = tiles, (tiles if resident else plan.kv_row_ids.size)
     if plan.gmo_head_cnt is not None:
         gmo = total(plan.gmo_head_cnt), plan.gmo_head_ids.size
     else:

@@ -33,7 +33,7 @@ from repro.configs.base import ArchConfig
 from repro.core.engine import (EngineConfig, merge_lane_states,
                                resolve_schedule, schedule_cache_stats)
 from repro.core.lru import LruCache
-from repro.core.plan import live_work
+from repro.core.plan import csr_path, live_work
 from repro.core.strategy import strategy_key
 from repro.core.symbols import unpack_bits
 from repro.models import dit
@@ -87,7 +87,9 @@ def build_sampler(cfg: ArchConfig, ecfg: EngineConfig, scfg: SamplerConfig,
     live, grid)``: ``live`` / ``grid`` map each Dispatch kernel to the
     live work and the launched grid slots of the plan the step leaves in
     the engine state (:func:`repro.core.plan.live_work`, summed over
-    layers) — on a Dispatch step, the frozen plan its kernels ran on.
+    layers, for the CSR kernel path :func:`repro.core.plan.csr_path`
+    picks at these shapes) — on a Dispatch step, the frozen plan its
+    kernels ran on.
 
     Every op of a step sits under the step mode's named scope (``fo.dense``
     / ``fo.update`` / ``fo.dispatch``) and one block part (``fo.qkv``,
@@ -102,6 +104,7 @@ def build_sampler(cfg: ArchConfig, ecfg: EngineConfig, scfg: SamplerConfig,
     57 MB-per-block TaylorSeer state."""
     n_steps = scfg.num_steps
     dt = 1.0 / n_steps
+    resident = csr_path(ecfg, n_tokens, cfg.hd, scfg.dtype) == "resident"
 
     def step_fn(mode: str):
         def f(params, states, xe, te, t, row, i):
@@ -117,11 +120,12 @@ def build_sampler(cfg: ArchConfig, ecfg: EngineConfig, scfg: SamplerConfig,
     branches = [step_fn("dense"), step_fn("update"), step_fn("dispatch")]
 
     def metrics(states):
-        work = live_work(states.plan)
+        work = live_work(states.plan, resident)
         return (_density_device(states, ecfg, n_tokens),
                 _pair_sparsity_device(states, ecfg, n_tokens),
                 {k: live for k, (live, _) in work.items()},
-                {k: jnp.int32(slots) for k, (_, slots) in work.items()})
+                {k: jnp.asarray(slots, jnp.int32)
+                 for k, (_, slots) in work.items()})
 
     def body(params, patch_embed, text_emb, carry, xs):
         x, states = carry
@@ -164,9 +168,10 @@ def sample(params, cfg: ArchConfig, ecfg: EngineConfig, *,
 
     ``patch_embed``: (patch_dim, d_model) stub patchifier.  Returns the
     denoised latents (B, N_v, patch_dim).  ``trace`` (a list) receives one
-    ``{step, kind, density, pair_sparsity, live, grid}`` dict per step
-    (``live`` / ``grid``: live work and launched grid slots per Dispatch
-    kernel, see :func:`build_sampler`); ``stats`` (a
+    ``{step, kind, density, pair_sparsity, live, grid, csr_path}`` dict
+    per step (``live`` / ``grid``: live work and launched grid slots per
+    Dispatch kernel, see :func:`build_sampler`; ``csr_path``: the CSR
+    attention kernel's path, :func:`repro.core.plan.csr_path`); ``stats`` (a
     dict) receives ``executables`` (compiled-executable count for this
     call — exactly 1), ``lower`` (a thunk that lowers the sampler at this
     call's arguments, to inspect the compiled program), ``schedule`` (the
@@ -231,6 +236,7 @@ def sample(params, cfg: ArchConfig, ecfg: EngineConfig, *,
         stats["schedule_cache"] = schedule_cache_stats()
     if with_metrics:
         kinds = sched.kinds()
+        path = csr_path(ecfg, n_tokens, cfg.hd, scfg.dtype)
         with TraceAnnotation("fo.metrics"):
             # ONE host sync for the trace
             dens, pair_s, live, grid = jax.device_get(ys)
@@ -239,7 +245,8 @@ def sample(params, cfg: ArchConfig, ecfg: EngineConfig, *,
                     "step": i, "kind": kinds[i], "density": float(dens[i]),
                     "pair_sparsity": float(pair_s[i]),
                     "live": {k: int(v[i]) for k, v in live.items()},
-                    "grid": {k: int(v[i]) for k, v in grid.items()}})
+                    "grid": {k: int(v[i]) for k, v in grid.items()},
+                    "csr_path": path})
     return x
 
 

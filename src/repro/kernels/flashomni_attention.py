@@ -4,15 +4,26 @@ Two variants of the paper's Algorithm 1, adapted to the TPU execution model
 (DESIGN §2):
 
 ``flashomni_attention_csr``  (default, TPU-native structural skipping)
-    The grid covers only LIVE work: ``(BH, Cq, Ckv)`` where ``Cq`` is the
-    static capacity of live Q blocks and the KV reduction runs over
-    per-row CSR column lists.  Scalar-prefetched index arrays drive the
-    BlockSpec index maps, so skipped tiles are never DMA'd and never
-    occupy a grid slot — this is what preserves the paper's ~1:1
-    speedup:sparsity on a sequential-grid machine.  Cached rows are left
-    untouched via input/output aliasing of the ``o_reuse`` tensor (their
-    forecast value is produced by the ``taylor_reuse`` element-wise kernel,
-    the paper's "alternatively, an elementwise kernel can be invoked").
+    The KV reduction runs over per-row CSR column lists for only the
+    ``Cq`` live Q blocks (static capacity); scalar-prefetched index arrays
+    drive the BlockSpec index maps and the in-kernel walk, so skipped
+    tiles are never read.  Cached rows are left untouched via input/output
+    aliasing of the ``o_reuse`` tensor (their forecast value is produced
+    by the ``taylor_reuse`` element-wise kernel, the paper's
+    "alternatively, an elementwise kernel can be invoked").  Two grids,
+    chosen from the shapes (:func:`csr_resident`):
+
+    * resident (K/V of one head fit :data:`RESIDENT_VMEM_BYTES`): grid
+      ``(BH, Cq)``, one step per q-block row.  The head's whole K and V
+      are one VMEM block each, fetched once per head (the pipeline
+      prefetches the next head's behind the current head's rows); the
+      body walks the row's live KV blocks, a few at a time, slicing them
+      out of VMEM.  Padding rows (past ``q_cnt``) do nothing.
+    * streaming (longer sequences): grid ``(BH, Cq, Ckv)``, one 64x64
+      tile per grid step, each DMA'd by its index map.
+
+    Both apply the same online-softmax update per KV block in ascending
+    id order, so their outputs are bit-identical.
 
 ``flashomni_attention_csr_bucketed``  (occupancy-bucketed two-level grid)
     The uniform CSR grid still pads every live row's reduction to the
@@ -54,18 +65,70 @@ from jax.experimental.pallas import tpu as pltpu
 
 
 __all__ = [
+    "RESIDENT_VMEM_BYTES",
+    "csr_resident",
     "flashomni_attention_csr",
     "flashomni_attention_csr_bucketed",
     "flashomni_attention_symbols",
 ]
 
 _NEG_INF = -1e30
-_LANES = 128  # TPU vreg lane count: m/l scratch kept (bq, 128)-shaped.
+_LANES = 128  # TPU vreg lane count: m/l kept (bq, 128)-shaped.
+# VMEM the resident CSR walk may give to one head's K and V, double-
+# buffered: under v5e's 16 MiB default scoped limit, with room for the
+# Q/O blocks and the body's f32 temporaries.
+RESIDENT_VMEM_BYTES = 12 * 2**20
+# KV blocks one iteration of the resident walk takes: their scores issue
+# together, so one block's matmuls overlap another's softmax.  At flux
+# width on one v5e a call took 29.3 / 17.0 / 10.4 / 7.3 ms at 1 / 2 / 4 / 8.
+_WALK_GROUP = 8
 
 
 # ---------------------------------------------------------------------------
 # CSR variant
 # ---------------------------------------------------------------------------
+
+def _scores(q, k, scale: float):
+    """(bq, bk) scaled scores of f32 ``q`` (bq, d) against a K block."""
+    return jax.lax.dot_general(q, k.astype(jnp.float32),
+                               (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32) * scale
+
+
+def _online_softmax(s, v, m, l, acc):
+    """One online-softmax step over a block's scores ``s`` and V block.
+    ``m`` and ``l`` are lane-broadcast (bq, 128), ``acc`` f32 (bq, d).
+    Returns the updated ``(m, l, acc)``."""
+    m_prev = m[:, :1]                                       # (bq, 1)
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    p = jnp.exp(s - m_new)
+    alpha = jnp.exp(m_prev - m_new)                         # (bq, 1)
+    l = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    acc = acc * alpha + jax.lax.dot(p, v.astype(jnp.float32),
+                                    preferred_element_type=jnp.float32)
+    return jnp.broadcast_to(m_new, m.shape), l, acc
+
+
+def _normalize(acc, l):
+    l = l[:, :1]
+    return acc / jnp.where(l == 0.0, 1.0, l)                # fully-skipped row guard
+
+
+def _init(acc_ref, m_ref, l_ref):
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+
+
+def _tile_update(q, k, v, acc_ref, m_ref, l_ref, scale: float):
+    """:func:`_online_softmax` on the scratch refs of a grid kernel."""
+    m_ref[...], l_ref[...], acc_ref[...] = _online_softmax(
+        _scores(q, k, scale), v, m_ref[...], l_ref[...], acc_ref[...])
+
+
+def _finalize(o_ref, acc_ref, l_ref):
+    o_ref[0] = _normalize(acc_ref[...], l_ref[...]).astype(o_ref.dtype)
+
 
 def _csr_kernel(
     # scalar prefetch
@@ -84,33 +147,78 @@ def _csr_kernel(
     bh = pl.program_id(0)
 
     @pl.when(j == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+    def _():
+        _init(acc_ref, m_ref, l_ref)
 
     @pl.when(j < kv_cnt_ref[bh, c])
-    def _compute():
-        q = q_ref[0].astype(jnp.float32)                    # (bq, d)
-        k = k_ref[0].astype(jnp.float32)                    # (bk, d)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        m_prev = m_ref[:, :1]                               # (bq, 1)
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)                     # (bq, 1)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-        v = v_ref[0].astype(jnp.float32)
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot(
-            p, v, preferred_element_type=jnp.float32)
+    def _():
+        _tile_update(q_ref[0].astype(jnp.float32), k_ref[0], v_ref[0],
+                     acc_ref, m_ref, l_ref, scale)
 
     @pl.when(j == ckv - 1)
-    def _finalize():
-        l = l_ref[:, :1]
-        l = jnp.where(l == 0.0, 1.0, l)                     # fully-skipped row guard
-        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
+    def _():
+        _finalize(o_ref, acc_ref, l_ref)
+
+
+def _csr_resident_kernel(
+    # scalar prefetch
+    q_ids_ref, q_src_ids_ref, kv_ids_ref, kv_cnt_ref, q_cnt_ref,
+    # inputs
+    q_ref, k_ref, v_ref, o_reuse_ref,   # k/v: the head's whole (1, N_kv, d)
+    # outputs
+    o_ref,
+    *,
+    scale: float,
+    ckv: int,
+    block_kv: int,
+):
+    bh, c = pl.program_id(0), pl.program_id(1)
+
+    # Padding rows repeat the last live row's output block, which stays
+    # resident across consecutive steps with the same index: leaving it
+    # unwritten keeps what that row wrote.
+    @pl.when(c < q_cnt_ref[bh])
+    def _():
+        q = q_ref[0].astype(jnp.float32)
+        n = kv_cnt_ref[bh, c]
+
+        def take(live, j0):
+            """The carry after the ``live`` blocks from slot ``j0``: their
+            scores are issued together, the updates then run in order."""
+            def update(carry):
+                rows = [pl.ds(pl.multiple_of(
+                    kv_ids_ref[bh, c * ckv + j0 + i] * block_kv, block_kv),
+                    block_kv) for i in range(live)]
+                scores = [_scores(q, k_ref[0, r, :], scale) for r in rows]
+                for s, r in zip(scores, rows):
+                    carry = _online_softmax(s, v_ref[0, r, :], *carry)
+                return carry
+            return update
+
+        group = min(_WALK_GROUP, ckv)
+
+        def walk(g, carry):
+            j0 = g * group
+            live = jnp.clip(n - j0, 0, group)
+            return jax.lax.switch(
+                live, [take(i, j0) for i in range(group + 1)], carry)
+
+        # (m, l, acc) ride the loop carry rather than VMEM scratch: 18%
+        # less time a call at one block an iteration (flux width, v5e).
+        bq, d = q.shape
+        m, l, acc = jax.lax.fori_loop(0, pl.cdiv(ckv, group), walk, (
+            jnp.full((bq, _LANES), _NEG_INF, jnp.float32),
+            jnp.zeros((bq, _LANES), jnp.float32),
+            jnp.zeros((bq, d), jnp.float32)))
+        o_ref[0] = _normalize(acc, l).astype(o_ref.dtype)
+
+
+def csr_resident(n_kv: int, d: int, itemsize: int) -> bool:
+    """Whether the CSR kernel keeps a head's K and V resident in VMEM:
+    both, double-buffered (lanes padded to 128), fit
+    :data:`RESIDENT_VMEM_BYTES`."""
+    lanes = -(-d // _LANES) * _LANES
+    return 4 * n_kv * lanes * itemsize <= RESIDENT_VMEM_BYTES
 
 
 def flashomni_attention_csr(
@@ -121,6 +229,7 @@ def flashomni_attention_csr(
     q_ids: jax.Array,         # (BH, Cq) int32 live q-block ids (output layout)
     kv_ids: jax.Array,        # (BH, Cq, Ckv) int32 per-row live kv-block ids
     kv_cnt: jax.Array,        # (BH, Cq) int32
+    q_cnt: jax.Array,         # (BH,) int32 live rows per head
     *,
     block_q: int,
     block_kv: int,
@@ -132,7 +241,24 @@ def flashomni_attention_csr(
     are READ from where outputs are WRITTEN: pass the compact-slot ids of a
     GEMM-Q ``(Cr·bm, F)`` output to chain the two kernels without a scatter
     (the compact-layout fusion GEMM-Q was designed for).  Defaults to
-    ``q_ids`` (full-layout Q)."""
+    ``q_ids`` (full-layout Q).
+
+    Runs the resident walk where :func:`csr_resident` holds for K, else
+    the streaming grid.  Padding rows (past ``q_cnt``) must repeat the
+    last live row's ids, as :func:`repro.core.symbols.active_indices`
+    pads them.  Block ``q_ids[bh, 0]`` of a head with ``q_cnt`` 0 is left
+    undefined: callers keep ``o_reuse`` for such heads."""
+    return _csr_call(q, k, v, o_reuse, q_ids, kv_ids, kv_cnt, q_cnt,
+                     block_q=block_q, block_kv=block_kv, scale=scale,
+                     interpret=interpret, q_src_ids=q_src_ids,
+                     resident=csr_resident(k.shape[1], k.shape[2],
+                                           k.dtype.itemsize))
+
+
+def _csr_call(q, k, v, o_reuse, q_ids, kv_ids, kv_cnt, q_cnt, *,
+              block_q: int, block_kv: int, scale: Optional[float],
+              interpret: bool, q_src_ids: Optional[jax.Array],
+              resident: bool) -> jax.Array:
     bhs, n_q, d = q.shape
     n_kv = k.shape[1]
     assert n_q % block_q == 0 and n_kv % block_kv == 0
@@ -140,50 +266,68 @@ def flashomni_attention_csr(
     cq, ckv = q_ids.shape[1], kv_ids.shape[2]
     scale = (d ** -0.5) if scale is None else scale
     q_src_ids = q_ids if q_src_ids is None else q_src_ids
-
-    grid = (bhs, cq, ckv)
-    kernel = functools.partial(_csr_kernel, scale=scale, ckv=ckv)
     flat_kv = kv_ids.reshape(bhs, cq * ckv)
 
-    def q_map(bh, c, j, q_ids_ref, q_src_ids_ref, kv_ids_ref, kv_cnt_ref):
-        return (bh, q_src_ids_ref[bh, c], 0)
+    if resident:
+        grid = (bhs, cq)
+        kernel = functools.partial(_csr_resident_kernel, scale=scale,
+                                   ckv=ckv, block_kv=block_kv)
+        prefetch = (q_ids, q_src_ids, flat_kv, kv_cnt, q_cnt)
+        scratch = []
 
-    def kv_map(bh, c, j, q_ids_ref, q_src_ids_ref, kv_ids_ref, kv_cnt_ref):
-        # Clamp padded slots to the last live column (re-DMA of a resident
-        # block — Mosaic elides the copy when the index is unchanged).
-        jj = jnp.maximum(jnp.minimum(j, kv_cnt_ref[bh, c] - 1), 0)
-        return (bh, kv_ids_ref[bh, c * ckv + jj], 0)
+        def q_map(bh, c, q_ids_ref, q_src_ids_ref, *_):
+            return (bh, q_src_ids_ref[bh, c], 0)
 
-    def o_map(bh, c, j, q_ids_ref, q_src_ids_ref, kv_ids_ref, kv_cnt_ref):
-        return (bh, q_ids_ref[bh, c], 0)
+        def o_map(bh, c, q_ids_ref, *_):
+            return (bh, q_ids_ref[bh, c], 0)
+
+        kv_spec = pl.BlockSpec((1, n_kv, d), lambda bh, c, *_: (bh, 0, 0))
+    else:
+        grid = (bhs, cq, ckv)
+        kernel = functools.partial(_csr_kernel, scale=scale, ckv=ckv)
+        prefetch = (q_ids, q_src_ids, flat_kv, kv_cnt)
+
+        def q_map(bh, c, j, q_ids_ref, q_src_ids_ref, kv_ids_ref, kv_cnt_ref):
+            return (bh, q_src_ids_ref[bh, c], 0)
+
+        def kv_map(bh, c, j, q_ids_ref, q_src_ids_ref, kv_ids_ref, kv_cnt_ref):
+            # Clamp padded slots to the last live column (re-DMA of a
+            # resident block — Mosaic elides the copy when the index is
+            # unchanged).
+            jj = jnp.maximum(jnp.minimum(j, kv_cnt_ref[bh, c] - 1), 0)
+            return (bh, kv_ids_ref[bh, c * ckv + jj], 0)
+
+        def o_map(bh, c, j, q_ids_ref, q_src_ids_ref, kv_ids_ref, kv_cnt_ref):
+            return (bh, q_ids_ref[bh, c], 0)
+
+        kv_spec = pl.BlockSpec((1, block_kv, d), kv_map)
+        scratch = [pltpu.VMEM((block_q, d), jnp.float32),
+                   pltpu.VMEM((block_q, _LANES), jnp.float32),
+                   pltpu.VMEM((block_q, _LANES), jnp.float32)]
 
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
+            num_scalar_prefetch=len(prefetch),
             grid=grid,
             in_specs=[
                 pl.BlockSpec((1, block_q, d), q_map),
-                pl.BlockSpec((1, block_kv, d), kv_map),
-                pl.BlockSpec((1, block_kv, d), kv_map),
+                kv_spec,
+                kv_spec,
                 pl.BlockSpec((1, block_q, d), o_map),       # o_reuse (aliased)
             ],
             out_specs=pl.BlockSpec((1, block_q, d), o_map),
-            scratch_shapes=[
-                pltpu.VMEM((block_q, d), jnp.float32),
-                pltpu.VMEM((block_q, _LANES), jnp.float32),
-                pltpu.VMEM((block_q, _LANES), jnp.float32),
-            ],
+            scratch_shapes=scratch,
         ),
         out_shape=jax.ShapeDtypeStruct(o_reuse.shape, o_reuse.dtype),
         # NB: alias indices count the scalar-prefetch operands too.
-        input_output_aliases={7: 0},                        # o_reuse -> out
+        input_output_aliases={len(prefetch) + 3: 0},        # o_reuse -> out
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            dimension_semantics=("parallel",) + ("arbitrary",) * (len(grid) - 1),
         ),
         interpret=interpret,
         name="flashomni_csr_attention",
-    )(q_ids, q_src_ids, flat_kv, kv_cnt, q, k, v, o_reuse)
+    )(*prefetch, q, k, v, o_reuse)
 
 
 # ---------------------------------------------------------------------------
@@ -208,33 +352,17 @@ def _csr_bucketed_kernel(
     jof = jof_ref[s]
 
     @pl.when(jof == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+    def _():
+        _init(acc_ref, m_ref, l_ref)
 
     @pl.when(jof < kv_cnt_ref[b, r])
-    def _compute():
-        q = q_ref[0].astype(jnp.float32)                    # (bq, d)
-        k = k_ref[0].astype(jnp.float32)                    # (bk, d)
-        s_ = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32) * scale
-        m_prev = m_ref[:, :1]                               # (bq, 1)
-        m_cur = jnp.max(s_, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s_ - m_new)
-        alpha = jnp.exp(m_prev - m_new)                     # (bq, 1)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-        v = v_ref[0].astype(jnp.float32)
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot(
-            p, v, preferred_element_type=jnp.float32)
+    def _():
+        _tile_update(q_ref[0].astype(jnp.float32), k_ref[0], v_ref[0],
+                     acc_ref, m_ref, l_ref, scale)
 
     @pl.when(slast_ref[s] == 1)
-    def _finalize():
-        l = l_ref[:, :1]
-        l = jnp.where(l == 0.0, 1.0, l)                     # fully-skipped row guard
-        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
+    def _():
+        _finalize(o_ref, acc_ref, l_ref)
 
 
 def flashomni_attention_csr_bucketed(
@@ -362,10 +490,8 @@ def _sym_kernel(
     j_live = (byte_s >> (7 - flat % 8)) & 1
 
     @pl.when(j == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+    def _():
+        _init(acc_ref, m_ref, l_ref)
 
     # Cache-then-Reuse (Algorithm 1 lines 5-10): fused element-wise copy of
     # the forecast feature, then the CTA-equivalent returns.
@@ -375,26 +501,13 @@ def _sym_kernel(
 
     # Compute-on-Demand (lines 11-19) with reduction-axis skipping (line 13).
     @pl.when((f_live == 1) & (j_live == 1))
-    def _compute():
-        q = q_ref[0].astype(jnp.float32)
-        k = k_ref[0].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        m_prev = m_ref[:, :1]
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot(
-            p, v_ref[0].astype(jnp.float32), preferred_element_type=jnp.float32)
+    def _():
+        _tile_update(q_ref[0].astype(jnp.float32), k_ref[0], v_ref[0],
+                     acc_ref, m_ref, l_ref, scale)
 
     @pl.when((f_live == 1) & (j == t_kv - 1))
-    def _finalize():
-        l = l_ref[:, :1]
-        l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
+    def _():
+        _finalize(o_ref, acc_ref, l_ref)
 
 
 def flashomni_attention_symbols(

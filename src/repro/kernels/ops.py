@@ -122,10 +122,10 @@ def flashomni_attention(
             heads=heads, block_q=block_q, block_kv=block_kv,
             interpret=interpret)
     out = flashomni_attention_csr(
-        q, k, v, o_reuse, q_ids, kv_ids, kv_cnt,
+        q, k, v, o_reuse, q_ids, kv_ids, kv_cnt, q_cnt,
         block_q=block_q, block_kv=block_kv, interpret=interpret)
-    # Degenerate all-cached guard: the kernel writes garbage into the
-    # duplicated slot-0 block when q_cnt == 0; select the pure-reuse tensor.
+    # Degenerate all-cached guard: the kernel leaves the duplicated slot-0
+    # block undefined when q_cnt == 0; select the pure-reuse tensor.
     any_live = (q_cnt > 0)[:, None, None]
     return jnp.where(any_live, out, o_reuse)
 

@@ -65,6 +65,104 @@ def test_attention_csr_with_capacity():
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
 
 
+# ---------------------------------------------------------------------------
+# CSR attention: resident walk vs streaming grid
+# ---------------------------------------------------------------------------
+
+def _csr_plan(seed, bh, t, cap_q, cap_kv, p_c=0.6, p_s=0.5):
+    """Per-row CSR lists as the plan builds them, with a live row that has
+    no KV block (head 1, first live row) and padding rows past q_cnt."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 2)
+    m_c = jax.random.bernoulli(ks[0], p_c, (bh, t)).at[1, 0].set(True)
+    m_s = jax.random.bernoulli(ks[1], p_s, (bh, t, t)).at[1, 0].set(False)
+    q_ids, q_cnt = active_indices(m_c, cap_q)
+    rows = jnp.take_along_axis(m_s, q_ids[..., None], axis=-2)
+    kv_ids, kv_cnt = active_indices(rows, cap_kv)
+    return q_ids, q_cnt, kv_ids, kv_cnt
+
+
+CSR_PATH_CASES = {
+    # name: (bh, n, d, blk, cap_q, cap_kv, compact)
+    "full_lists": (3, 256, 64, 32, 8, 8, False),
+    "cap_kv_truncates": (3, 256, 64, 32, 8, 3, False),
+    "padding_rows": (3, 256, 32, 16, 16, 16, False),
+    "compact_q": (3, 256, 64, 32, 8, 5, True),
+}
+
+
+@pytest.mark.parametrize("case", CSR_PATH_CASES)
+def test_resident_walk_matches_streaming_bitwise(case):
+    from repro.kernels.flashomni_attention import _csr_call
+    bh, n, d, blk, cap_q, cap_kv, compact = CSR_PATH_CASES[case]
+    q, k, v, _, _, o_reuse = _attn_inputs(11, bh, n, d, blk, blk)
+    q_ids, q_cnt, kv_ids, kv_cnt = _csr_plan(5, bh, n // blk, cap_q, cap_kv)
+    assert int(q_cnt.min()) < cap_q            # some heads carry padding rows
+    assert int(kv_cnt[1, 0]) == 0              # a live row with no KV block
+    q_src = None
+    if compact:
+        # Live Q blocks packed at the front of a compact (cap_q·blk, d) Q.
+        q_src = jnp.broadcast_to(jnp.arange(cap_q, dtype=jnp.int32),
+                                 q_ids.shape)
+        q_src = jnp.minimum(q_src, jnp.maximum(q_cnt[:, None] - 1, 0))
+        q = q[:, :cap_q * blk]
+    out = {res: _csr_call(q, k, v, o_reuse, q_ids, kv_ids, kv_cnt, q_cnt,
+                          block_q=blk, block_kv=blk, scale=None,
+                          interpret=True, q_src_ids=q_src, resident=res)
+           for res in (False, True)}
+    np.testing.assert_array_equal(np.asarray(out[True]),
+                                  np.asarray(out[False]))
+    # The row with no KV block writes zeros; cached blocks keep o_reuse.
+    zero_blk = int(q_ids[1, 0])
+    np.testing.assert_array_equal(
+        np.asarray(out[True][1, zero_blk * blk:(zero_blk + 1) * blk]), 0.0)
+    live = np.zeros((bh, n // blk), bool)
+    for b in range(bh):
+        live[b, np.asarray(q_ids[b, :int(q_cnt[b])])] = True
+    cached = np.repeat(~live, blk, axis=1)
+    np.testing.assert_array_equal(np.asarray(out[True])[cached],
+                                  np.asarray(o_reuse)[cached])
+
+
+def test_resident_walk_keeps_o_reuse_on_a_dead_head(monkeypatch):
+    """A head with q_cnt 0 runs no row; ``PallasBackend.attention``'s guard
+    returns its ``o_reuse``, and the other heads equal the streaming grid's."""
+    from repro.core import EngineConfig, MaskConfig
+    from repro.core.backend import PallasBackend
+    from repro.core.plan import build_dispatch_plan
+    from repro.kernels import flashomni_attention as fa
+    b, h, n, d, blk = 1, 3, 128, 32, 16
+    t = n // blk
+    cfg = EngineConfig(mask=MaskConfig(pool=blk, block_q=blk, block_kv=blk),
+                       cap_q_frac=1.0, cap_kv_frac=1.0, backend="pallas")
+    ks = jax.random.split(jax.random.PRNGKey(4), 6)
+    q, k, v, o_reuse = (jax.random.normal(kk, (b, h, n, d)) for kk in ks[:4])
+    m_c = jax.random.bernoulli(ks[4], 0.6, (b, h, t)).at[:, 0].set(False)
+    m_s = jax.random.bernoulli(ks[5], 0.5, (b, h, t, t))
+    plan = build_dispatch_plan(m_c, m_s, cfg, n)
+    assert int(plan.q_cnt[0, 0]) == 0
+    spec = cfg.caps(n)
+    attn = lambda: PallasBackend(interpret=True).attention(
+        q, k, v, o_reuse, plan, spec)
+    resident = attn()
+    monkeypatch.setattr(fa, "csr_resident", lambda *a: False)
+    streaming = attn()
+    np.testing.assert_array_equal(np.asarray(resident), np.asarray(streaming))
+    np.testing.assert_array_equal(np.asarray(resident[:, 0]),
+                                  np.asarray(o_reuse[:, 0]))
+
+
+@pytest.mark.parametrize("n_kv,d,itemsize,resident", [
+    (4608, 128, 2, True),        # flux width, bf16: 4.72 MB
+    (4608, 128, 4, True),        # flux width, f32: 9.44 MB
+    (33_024, 128, 2, False),     # hunyuan length: 33.8 MB
+    (4608, 64, 4, True),         # lanes pad d 64 to 128
+    (8192, 64, 4, False),        # ... and count: 16.8 MB, not 8.4 MB
+])
+def test_csr_path_is_chosen_from_the_shapes(n_kv, d, itemsize, resident):
+    from repro.kernels.flashomni_attention import csr_resident
+    assert csr_resident(n_kv, d, itemsize) is resident
+
+
 GEMM_SWEEP = [
     (128, 64, 128, 16, jnp.float32, 1e-4),
     (256, 128, 256, 32, jnp.float32, 1e-4),

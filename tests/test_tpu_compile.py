@@ -70,20 +70,47 @@ def _compile(fn, one_chip, *shapes):
     return jax.jit(fn).lower(*args).compile()
 
 
-def test_csr_attention_compiles_at_flux_width(one_chip):
-    from repro.kernels.flashomni_attention import flashomni_attention_csr
+def _csr_shapes(n_kv):
     bh, bf, i32 = H, jnp.bfloat16, jnp.int32
+    return (((bh, CAP_ROWS * POOL, DH), bf), ((bh, n_kv, DH), bf),
+            ((bh, n_kv, DH), bf), ((bh, N_TOKENS, DH), bf),
+            ((bh, SPEC.cap_q), i32), ((bh, SPEC.cap_q, SPEC.cap_kv), i32),
+            ((bh, SPEC.cap_q), i32), ((bh,), i32), ((bh, SPEC.cap_q), i32))
+
+
+def test_csr_attention_compiles_at_flux_width(one_chip):
+    """At flux width (B·H 24, N 4,608, d 128) the entry takes the resident
+    walk: K and V of a head held in VMEM, one grid step per q-block row."""
+    from repro.kernels.flashomni_attention import (csr_resident,
+                                                   flashomni_attention_csr)
+    assert csr_resident(N_TOKENS, DH, 2)
     fn = functools.partial(flashomni_attention_csr, block_q=SPEC.block_q,
                            block_kv=SPEC.block_kv)
     compiled = _compile(
-        lambda q, k, v, o, qi, ki, kc, qs: fn(q, k, v, o, qi, ki, kc,
-                                              q_src_ids=qs),
-        one_chip,
-        ((bh, CAP_ROWS * POOL, DH), bf), ((bh, N_TOKENS, DH), bf),
-        ((bh, N_TOKENS, DH), bf), ((bh, N_TOKENS, DH), bf),
-        ((bh, SPEC.cap_q), i32), ((bh, SPEC.cap_q, SPEC.cap_kv), i32),
-        ((bh, SPEC.cap_q), i32), ((bh, SPEC.cap_q), i32))
-    assert "tpu_custom_call" in compiled.as_text()
+        lambda q, k, v, o, qi, ki, kc, qc, qs: fn(q, k, v, o, qi, ki, kc, qc,
+                                                  q_src_ids=qs),
+        one_chip, *_csr_shapes(N_TOKENS))
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert "flashomni_csr_attention" in text
+
+
+def test_streaming_csr_attention_compiles_past_the_resident_budget(one_chip):
+    """Past the VMEM budget (hunyuan's 33,024 tokens) the entry keeps the
+    streaming grid, one K/V tile DMA'd per grid step."""
+    from repro.kernels.flashomni_attention import (csr_resident,
+                                                   flashomni_attention_csr)
+    n_kv = 33_024
+    assert not csr_resident(n_kv, DH, 2)
+    fn = functools.partial(flashomni_attention_csr, block_q=SPEC.block_q,
+                           block_kv=SPEC.block_kv)
+    compiled = _compile(
+        lambda q, k, v, o, qi, ki, kc, qc, qs: fn(q, k, v, o, qi, ki, kc, qc,
+                                                  q_src_ids=qs),
+        one_chip, *_csr_shapes(n_kv))
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert "flashomni_csr_attention" in text
 
 
 def test_gemm_q_compiles_at_flux_width(one_chip):
