@@ -26,8 +26,9 @@ from chipbench import run as R
 
 
 def readings(cell: dict, seeds, control: bool, engine_overrides=None,
-             index: int = 1):
-    """Yield one dict of readings per seed."""
+             index: int = 1, devices=None):
+    """Yield one dict of readings per seed, the reference computed on
+    ``devices`` (default: one chip)."""
     import jax.numpy as jnp
 
     from chipbench import reference
@@ -37,7 +38,7 @@ def readings(cell: dict, seeds, control: bool, engine_overrides=None,
         server = R.Server(cell, seed, engine_overrides)
         res = server.serve(server.request(index))
         served = time.perf_counter() - t
-        x0, ref = server.reference(index)
+        x0, ref = server.reference(index, devices=devices)
         row = {"seed": seed, "served_s": served,
                "reference_s": time.perf_counter() - t - served,
                "program": reference.compare(res["out"], ref, x0)}
@@ -45,7 +46,8 @@ def readings(cell: dict, seeds, control: bool, engine_overrides=None,
                 if st["kind"] == "dispatch"]
         row["dispatch_density"] = sum(dens) / max(len(dens), 1)
         if control:
-            _, low = server.reference(index, compute=jnp.float8_e4m3fn)
+            _, low = server.reference(index, compute=jnp.float8_e4m3fn,
+                                      devices=devices)
             row["control"] = reference.compare(low, ref, x0)
         del server
         yield row
@@ -65,11 +67,12 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     bench = B.load_benchmark()
     cell = B.find_cell(bench, args.workload)
-    R.require_tpu(cell["chips"])
+    devices = R.require_tpu(cell["chips"])
     R.import_program()
     from repro.launch.serve import enable_compile_cache
     enable_compile_cache()
-    for row in readings(cell, args.seeds, args.control, dict(args.engine)):
+    for row in readings(cell, args.seeds, args.control, dict(args.engine),
+                        devices=devices):
         print(json.dumps(row), flush=True)
     return 0
 

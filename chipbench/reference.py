@@ -22,8 +22,13 @@ Everything is written out here in ``jax.numpy``: nothing of the program
 under test is imported.  Matrix products take their operands rounded to
 ``compute`` (float32 for the reference, a float8 type for the control)
 and accumulate in float32 at the highest precision; everything else is
-float32.  One jitted step per mode scans the blocks, and attention runs
-head by head, so the reference fits beside the weights on one chip.
+float32.  One jitted step per mode scans the blocks.  Attention runs head
+by head and, within a head, ``QUERY_ROWS`` query rows at a time, each
+with an exact softmax over all keys: no (N, N) array is ever formed.  The
+activations and the Taylor state are split by token over a mesh of the
+cell's chips (one chip holds them whole), and each chip attends its own
+query rows to all keys, so a 33K-token request fits within one v5e's
+memory per chip.
 """
 
 from __future__ import annotations
@@ -34,9 +39,12 @@ import math
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 F32 = jnp.float32
 HIGHEST = jax.lax.Precision.HIGHEST
+QUERY_ROWS = 768          # six 128-token blocks; divides 4,608 and 33,024
+TOKENS = "tokens"         # the mesh axis that splits the tokens
 
 
 def _mm(spec: str, a, b, compute):
@@ -116,24 +124,60 @@ def symbols(q, k, rc: dict):
     return m_c & rows[:, None, :], m_s
 
 
-def _attention(q, k, v, allow, compute):
-    """Softmax attention head by head.  q, k, v (B, H, N, dh); ``allow``
-    (B, H, T, T) block mask or None (dense)."""
-    b, h, n, dh = q.shape
+def _attention(q, k, v, allow, compute, row0=0):
+    """Softmax attention head by head, ``QUERY_ROWS`` query rows at a time.
+
+    q (B, H, M, dh) holds query rows ``row0`` to ``row0 + M`` of the N
+    keys in k, v (B, H, N, dh); ``allow`` (B, H, T, T) is the block mask
+    over all N tokens, or None (dense).  A last chunk of fewer rows takes
+    what is left."""
+    b, h, m, dh = q.shape
+    n = k.shape[2]
+    rows = min(QUERY_ROWS, m)
+    whole = m - m % rows
     flat = lambda a: a.reshape(b * h, *a.shape[2:])
+
+    def chunk(qc, kh, vh, mh, start):
+        s = _mm("qd,kd->qk", qc, kh, compute) * dh ** -0.5
+        if mh is not None:
+            pool = n // mh.shape[-1]
+            blk = (row0 + start + jnp.arange(qc.shape[0])) // pool
+            s = jnp.where(jnp.repeat(mh[blk], pool, axis=1), s, -jnp.inf)
+        return _mm("qk,kd->qd", jax.nn.softmax(s, axis=-1), vh, compute)
 
     def one(args):
         qh, kh, vh, mh = args
-        s = _mm("qd,kd->qk", qh, kh, compute) * dh ** -0.5
-        if mh is not None:
-            rep = n // mh.shape[-1]
-            tok = jnp.repeat(jnp.repeat(mh, rep, axis=0), rep, axis=1)
-            s = jnp.where(tok, s, -jnp.inf)
-        return _mm("qk,kd->qd", jax.nn.softmax(s, axis=-1), vh, compute)
+        o = jax.lax.map(
+            lambda a: chunk(a[0], kh, vh, mh, a[1]),
+            (qh[:whole].reshape(-1, rows, dh), jnp.arange(0, whole, rows)))
+        o = o.reshape(whole, dh)
+        if whole < m:
+            o = jnp.concatenate([o, chunk(qh[whole:], kh, vh, mh, whole)])
+        return o
 
     masks = None if allow is None else flat(allow)
     o = jax.lax.map(one, (flat(q), flat(k), flat(v), masks))
-    return o.reshape(b, h, n, dh)
+    return o.reshape(b, h, m, dh)
+
+
+def _split_attention(q, k, v, allow, compute, mesh):
+    """``_attention`` with the query rows split over ``mesh``: each chip
+    attends its own rows to all keys, gathered to it."""
+    by_row = P(None, None, TOKENS, None)
+
+    def local(q, k, v, allow):
+        row0 = jax.lax.axis_index(TOKENS) * q.shape[2]
+        return _attention(q, k, v, allow, compute, row0=row0)
+
+    return jax.shard_map(local, mesh=mesh, in_specs=(by_row, P(), P(), P()),
+                         out_specs=by_row)(q, k, v, allow)
+
+
+def _by_token(a, mesh, axis):
+    """``a`` split by token (dimension ``axis``) over ``mesh``."""
+    spec = [None] * a.ndim
+    spec[axis] = TOKENS
+    return jax.lax.with_sharding_constraint(a, NamedSharding(mesh, P(*spec)))
 
 
 def _taylor_coeffs(order: int, k, interval: int):
@@ -141,7 +185,7 @@ def _taylor_coeffs(order: int, k, interval: int):
     return [x ** i / math.factorial(i) for i in range(order + 1)]
 
 
-def _block(rc, mode, p, st, x, t_emb, k_since, compute):
+def _block(rc, mode, p, st, x, t_emb, k_since, compute, mesh):
     d, heads, eps = rc["d_model"], rc["n_heads"], rc["eps"]
     p = jax.tree.map(lambda a: a.astype(F32), p)
     mod = _mm("bd,df->bf", jax.nn.silu(t_emb), p["adaln"], compute) + p["adaln_b"]
@@ -167,9 +211,9 @@ def _block(rc, mode, p, st, x, t_emb, k_since, compute):
         return _mm("bnhd,hdf->bnf", o, wo, compute)
 
     if mode == "dense":
-        out = project(_attention(q, k, v, None, compute), None)
+        out = project(_split_attention(q, k, v, None, compute, mesh), None)
     elif mode == "update":
-        o = _attention(q, k, v, None, compute)
+        o = _split_attention(q, k, v, None, compute, mesh)
         m_c, m_s = symbols(q, k, rc)
         out = project(o, None)
         reused = project(o, ~m_c)
@@ -177,11 +221,11 @@ def _block(rc, mode, p, st, x, t_emb, k_since, compute):
         for i in range(1, rc["order"] + 1):
             new.append(new[i - 1] - st["derivs"][i - 1])
         n_upd = st["n_updates"] + 1
-        derivs = jnp.stack([jnp.where(i < n_upd, di, 0.0)
-                            for i, di in enumerate(new)])
+        derivs = _by_token(jnp.stack([jnp.where(i < n_upd, di, 0.0)
+                                      for i, di in enumerate(new)]), mesh, 2)
         st = dict(m_c=m_c, m_s=m_s, derivs=derivs, n_updates=n_upd)
     else:
-        o = _attention(q, k, v, st["m_s"], compute)
+        o = _split_attention(q, k, v, st["m_s"], compute, mesh)
         coef = _taylor_coeffs(rc["order"], k_since, rc["interval"])
         forecast = sum(c * di for c, di in zip(coef, st["derivs"]))
         out = project(o, st["m_c"]) + forecast
@@ -189,26 +233,36 @@ def _block(rc, mode, p, st, x, t_emb, k_since, compute):
     xm = _modulate(_rms(x, eps), sh_m, sc_m)
     y = jax.nn.gelu(_mm("bnd,df->bnf", xm, p["mlp_wi"], compute))
     y = _mm("bnf,fd->bnd", y, p["mlp_wo"], compute)
-    return x + g_m[:, None] * y, st
+    return _by_token(x + g_m[:, None] * y, mesh, 1), st
 
 
-@functools.partial(jax.jit, static_argnames=("rc_items", "mode", "compute"),
+@functools.partial(jax.jit,
+                   static_argnames=("rc_items", "mode", "compute", "mesh"),
                    donate_argnames=("states",))
 def _step(params, states, x, text, patch_embed, t, k_since, *, rc_items,
-          mode, compute):
+          mode, compute, mesh):
+    """One sampler step over all blocks, the tokens split over ``mesh``."""
     rc = dict(rc_items)
-    h = jnp.concatenate([text, _mm("bnp,pd->bnd", x, patch_embed, compute)],
-                        axis=1)
+    h = _by_token(jnp.concatenate(
+        [text, _mm("bnp,pd->bnd", x, patch_embed, compute)], axis=1), mesh, 1)
     f32 = lambda a: a.astype(F32)
     t_emb = _mm("bk,kd->bd", _timestep_embedding(t * 1000.0),
                 f32(params["t_mlp1"]), compute)
     t_emb = _mm("bd,de->be", jax.nn.silu(t_emb), f32(params["t_mlp2"]), compute)
 
-    def body(h, layer):
-        p, st = layer
-        return _block(rc, mode, p, st, h, t_emb, k_since, compute)
+    # The states ride in the carry and are written in place, block by
+    # block: as scanned outputs they would be stacked into a second copy.
+    def body(carry, layer):
+        h, states = carry
+        p, i = layer
+        h, st = _block(rc, mode, p, jax.tree.map(lambda a: a[i], states), h,
+                       t_emb, k_since, compute, mesh)
+        if mode == "update":
+            states = jax.tree.map(lambda a, s: a.at[i].set(s), states, st)
+        return (h, states), None
 
-    h, states = jax.lax.scan(body, h, (params["blocks"], states))
+    (h, states), _ = jax.lax.scan(
+        body, (h, states), (params["blocks"], jnp.arange(rc["n_layers"])))
     mod = _mm("bd,df->bf", jax.nn.silu(t_emb), f32(params["final_mod"]),
               compute)
     sh, sc = jnp.split(mod, 2, axis=-1)
@@ -231,9 +285,11 @@ def step_mode(step: int, rc: dict) -> str:
     return "update" if update else "dispatch"
 
 
-def sample(params, x0, text, patch_embed, rc: dict, compute=F32):
-    """Denoise ``x0`` (B, N_v, patch_dim) in ``rc["steps"]`` steps; returns
-    the latents as a host array."""
+def sample(params, x0, text, patch_embed, rc: dict, compute=F32,
+           devices=None):
+    """Denoise ``x0`` (B, N_v, patch_dim) in ``rc["steps"]`` steps with
+    the tokens split over ``devices`` (default: one chip); returns the
+    latents as a host array."""
     b, nv, _ = x0.shape
     n = nv + text.shape[1]
     t_blocks = n // rc["pool"]
@@ -241,14 +297,29 @@ def sample(params, x0, text, patch_embed, rc: dict, compute=F32):
         raise ValueError(f"{n} tokens do not divide into {rc['pool']}-token "
                          "blocks")
     layers, h = rc["n_layers"], rc["n_heads"]
-    states = dict(
-        m_c=jnp.ones((layers, b, h, t_blocks), bool),
-        m_s=jnp.ones((layers, b, h, t_blocks, t_blocks), bool),
-        derivs=jnp.zeros((layers, rc["order"] + 1, b, n, rc["d_model"]), F32),
-        n_updates=jnp.zeros((layers,), jnp.int32))
+
+    def states():
+        return dict(
+            m_c=jnp.ones((layers, b, h, t_blocks), bool),
+            m_s=jnp.ones((layers, b, h, t_blocks, t_blocks), bool),
+            derivs=jnp.zeros((layers, rc["order"] + 1, b, n, rc["d_model"]),
+                             F32),
+            n_updates=jnp.zeros((layers,), jnp.int32))
+
+    devices = devices or jax.devices()[:1]
+    if nv % len(devices) or n % len(devices):
+        raise ValueError(f"{nv} image and {n} tokens do not split over "
+                         f"{len(devices)} chips")
+    mesh = Mesh(np.asarray(devices), (TOKENS,))
+    rep = NamedSharding(mesh, P())
+    params, patch_embed, text = jax.device_put(
+        (params, patch_embed, np.asarray(text, np.float32)), rep)
+    x = jax.device_put(np.asarray(x0, np.float32),
+                       NamedSharding(mesh, P(None, TOKENS)))
+    states = jax.jit(states, out_shardings=dict(
+        m_c=rep, m_s=rep, n_updates=rep,
+        derivs=NamedSharding(mesh, P(None, None, None, TOKENS))))()
     rc_items = tuple(sorted(rc.items()))
-    x = jnp.asarray(x0, F32)
-    text = jnp.asarray(text, F32)
     last = 0
     for i in range(rc["steps"]):
         mode = step_mode(i, rc)
@@ -257,7 +328,7 @@ def sample(params, x0, text, patch_embed, rc: dict, compute=F32):
         t = jnp.full((b,), i / rc["steps"], F32)
         x, states = _step(params, states, x, text, patch_embed, t,
                           jnp.int32(i - last), rc_items=rc_items, mode=mode,
-                          compute=compute)
+                          compute=compute, mesh=mesh)
     return np.asarray(x)
 
 

@@ -157,16 +157,18 @@ class Server:
                               patch_embed=self.patch_embed,
                               stats=stats)[req.rid]
 
-    def reference(self, index: int, compute=None) -> tuple:
+    def reference(self, index: int, compute=None, devices=None) -> tuple:
         """Request ``index``'s inputs (host) and the plain reference's
-        output for them, computed in ``compute`` (float32 by default)."""
+        output for them, computed in ``compute`` (float32 by default) on
+        ``devices`` (default: one chip)."""
         import jax.numpy as jnp
 
         from chipbench import reference
         x0, text = (np.asarray(a) for a in self.inputs(index))
         rc = B.reference_config(self.cell["model"], self.cell["mix"])
         out = reference.sample(self.params, x0, text, self.patch_embed, rc,
-                               compute=compute or jnp.float32)
+                               compute=compute or jnp.float32,
+                               devices=devices)
         return x0, out
 
 
@@ -256,7 +258,7 @@ def run_cell(bench: dict, cell: dict, seed: int, seconds: float, traced: bool,
     if completed:
         rec = completed[int(np.random.default_rng(seed).integers(
             len(completed)))]
-        x0, ref = server.reference(rec["index"])
+        x0, ref = server.reference(rec["index"], devices=devices)
         got = reference.compare(rec["out"], ref, x0)
         for name, limit in cell["model"]["correct"].items():
             checks[name] = {"value": got[name], "limit": limit}

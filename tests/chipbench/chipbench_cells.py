@@ -2,6 +2,7 @@
 configuration and traffic files with the widths, depth and steps cut to
 what the Pallas interpreter runs in seconds."""
 
+import functools
 import json
 
 from chipbench import bench as B
@@ -62,3 +63,57 @@ class TickClock:
     def perf_counter(self):
         self.now += 1.0
         return self.now
+
+
+def avals(jaxpr):
+    """Every abstract value of ``jaxpr`` and of the jaxprs inside it."""
+    import jax.extend.core
+
+    for eqn in jaxpr.eqns:
+        for var in (*eqn.invars, *eqn.outvars):
+            yield var.aval
+        for param in eqn.params.values():
+            for sub in param if isinstance(param, (list, tuple)) else [param]:
+                sub = getattr(sub, "jaxpr", sub)
+                if isinstance(sub, jax.extend.core.Jaxpr):
+                    yield from avals(sub)
+
+
+HUNYUAN_TOKENS = (256, 32_768)          # text, video: the paper's 33K setting
+
+
+def hunyuan_step_args(n_layers: int, place=lambda token_dim: None):
+    """The reference's settings and one step's arguments, as shapes, at
+    256 text + 32,768 video tokens and HunyuanVideo's widths (those of
+    flux-mmdit: d_model 3072, 24 heads x 128, d_ff 12,288), bf16 weights.
+    ``place(token_dim)`` gives each argument's sharding: ``token_dim`` is
+    the dimension split by token, or None where the argument is whole."""
+    import jax
+    import jax.numpy as jnp
+
+    spec = json.loads((B.ROOT / "chipbench" / "configs" /
+                       "flux-mmdit.json").read_text())
+    nt, nv = HUNYUAN_TOKENS
+    spec["n_layers"] = n_layers
+    spec["sizes"].update(n_text_tokens=nt, n_image_tokens=nv)
+    rc = B.reference_config(spec, {"schedule": "flashomni", "steps": 50})
+    s = spec["sizes"]
+    d, h, n = s["d_model"], s["n_heads"], nt + nv
+    t = n // rc["pool"]
+
+    def arg(shape, dtype, token_dim=None):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=place(token_dim))
+
+    params = jax.eval_shape(functools.partial(
+        M._params, s, n_layers, spec["weights"]["qk_norm_gain"],
+        jnp.bfloat16), jax.random.key(0))
+    params = jax.tree.map(lambda a: arg(a.shape, a.dtype), params)
+    states = dict(
+        m_c=arg((n_layers, 1, h, t), bool),
+        m_s=arg((n_layers, 1, h, t, t), bool),
+        derivs=arg((n_layers, rc["order"] + 1, 1, n, d), jnp.float32, 3),
+        n_updates=arg((n_layers,), jnp.int32))
+    f32 = jnp.float32
+    return rc, (params, states, arg((1, nv, s["patch_dim"]), f32, 1),
+                arg((1, nt, d), f32), arg((s["patch_dim"], d), f32),
+                arg((1,), f32), arg((), jnp.int32))
