@@ -1,0 +1,8 @@
+"""``gemm_q_roofline``, the GEMM-Q kernel's roofline share (%), read in the
+HunyuanVideo cell: the same reader, loaded by name."""
+
+from chipbench import bench as B
+
+
+def read(run):
+    return B.load_reader("gemm_q_roofline")(run)

@@ -29,6 +29,7 @@ Recursion rules: ``scan`` multiplies its body by ``length``;
 ``while_loop`` by 1 (trip count is dynamic — the estimate is a lower
 bound there, recorded in :attr:`CostEstimate.inexact`); ``cond`` /
 ``switch`` take the per-resource **max** over branches; ``pallas_call``
+takes the kernel's declared ``cost_estimate`` where it has one, else
 multiplies its kernel body by the grid size; everything else
 (``pjit``, ``custom_jvp/vjp``, ``remat``, ``shard_map``) sums at
 multiplier 1.
@@ -306,6 +307,15 @@ def _grid_size(eqn) -> float:
     return float(math.prod(int(g) for g in grid)) or 1.0
 
 
+def _declared_cost(ce) -> CostEstimate:
+    """A kernel's own ``pl.CostEstimate``: its FLOPs with one per
+    transcendental, as :func:`_default_cost` counts an ``exp``, and the
+    HBM bytes it moves.  The body's VMEM loads and stores are not HBM
+    traffic, so the grid-times-body walk would overcount them."""
+    return CostEstimate(flops=float(ce.flops + ce.transcendentals),
+                        hbm_bytes=float(ce.bytes_accessed))
+
+
 def _sub_jaxprs_of(eqn):
     from repro.analysis.jaxpr_walk import _sub_jaxprs
     return list(_sub_jaxprs(eqn.params))
@@ -356,6 +366,8 @@ def _cost(jaxpr, axis_sizes: dict) -> CostEstimate:
                                    for k, v in dict(mesh.shape).items()})
             for sub in subs:
                 total.add(_cost(sub, inner_axes))
+        elif name == "pallas_call" and eqn.params.get("cost_estimate"):
+            total.add(_declared_cost(eqn.params["cost_estimate"]))
         elif name == "pallas_call" and subs:
             mult = _grid_size(eqn)
             for sub in subs:

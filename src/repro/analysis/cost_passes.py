@@ -461,7 +461,8 @@ class UpdateAmortization:
                 interval)
             ctx.note(f"{self.name}: {backend} update {u.flops / dense.flops:.2f}x "
                      f"dense, dispatch {d.flops / dense.flops:.2f}x, "
-                     f"amortized {(u.flops + (interval - 1) * d.flops) / (interval * dense.flops):.2f}x")
+                     f"amortized {(u.flops + (interval - 1) * d.flops) / (interval * dense.flops):.2f}x, "
+                     f"update bytes {u.hbm_bytes / dense.hbm_bytes:.2f}x")
         return findings
 
 

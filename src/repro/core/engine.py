@@ -498,7 +498,7 @@ def update_layer(
         q, k = _qk(params, x, heads, freqs)
         v = _project_heads(x, params.wv, heads)
     with jax.named_scope("fo.attention"):
-        o = dense_attention(q, k, v)                           # (B,H,N,dh)
+        o = get_backend(cfg).dense_attention(q, k, v)          # (B,H,N,dh)
     ctx = StrategyContext(cfg=cfg, n_text=n_text, n_tokens=n,
                           layer_idx=layer_idx, step_idx=step_idx,
                           num_steps=num_steps)

@@ -99,6 +99,7 @@ __all__ = [
     "bucket_layout",
     "gmo_layout",
     "csr_path",
+    "csr_windows_at",
     "live_work",
     "occupancy_histogram",
     "OCC_BINS",
@@ -593,48 +594,59 @@ def build_dispatch_plan(m_c: jax.Array, m_s: jax.Array, cfg, n_tokens: int,
     return plan
 
 
-def csr_path(cfg, n_tokens: int, head_dim: int, dtype) -> str:
-    """The CSR attention kernel a Dispatch step runs at these shapes:
-    ``"bucketed"`` (``kv_buckets`` > 1 on one device), else ``"resident"``
-    or ``"streaming"``, as ``kernels.flashomni_attention.csr_resident``
-    decides on the K/V each call reads: the whole sequence, or on a
+def _csr_kv_len(cfg, n_tokens: int) -> int:
+    """Keys each uniform CSR call reads: the whole sequence, or on a
     sequence-sharded mesh the per-shard buffer (union blocks plus one pad
     block)."""
-    from repro.kernels.flashomni_attention import csr_resident
-    spec = cfg.caps(n_tokens)
-    n_kv = n_tokens
     if getattr(cfg, "mesh_sp", 1) > 1 and getattr(cfg, "mesh_axis",
                                                   "seq") == "seq":
         from repro.distributed.plan_shard import shard_geometry
+        spec = cfg.caps(n_tokens)
         t = -(-n_tokens // spec.block_kv)
         geom = shard_geometry(spec, t, t, cfg.mesh_sp,
                               getattr(cfg, "mesh_pair_slack", 1.5))
-        n_kv = (geom.cap_kv + 1) * spec.block_kv
-    elif spec.kv_buckets > 1:
+        return (geom.cap_kv + 1) * spec.block_kv
+    return n_tokens
+
+
+def csr_windows_at(cfg, n_tokens: int, head_dim: int, dtype) -> int:
+    """The VMEM windows the uniform CSR kernel cuts a head's K and V into
+    at these shapes (``kernels.flashomni_attention.csr_windows``)."""
+    from repro.kernels.flashomni_attention import csr_windows
+    return csr_windows(_csr_kv_len(cfg, n_tokens), head_dim,
+                       jnp.dtype(dtype).itemsize, cfg.mask.block_kv)
+
+
+def csr_path(cfg, n_tokens: int, head_dim: int, dtype) -> str:
+    """The CSR attention kernel a Dispatch step runs at these shapes:
+    ``"bucketed"`` (``kv_buckets`` > 1 on one device), else ``"resident"``
+    (K and V of a head in one VMEM window) or ``"windowed"``
+    (:func:`csr_windows_at` > 1)."""
+    seq_mesh = getattr(cfg, "mesh_sp", 1) > 1 and getattr(
+        cfg, "mesh_axis", "seq") == "seq"
+    if not seq_mesh and cfg.caps(n_tokens).kv_buckets > 1:
         return "bucketed"
-    itemsize = jnp.dtype(dtype).itemsize
-    return ("resident" if csr_resident(n_kv, head_dim, itemsize)
-            else "streaming")
+    return ("resident" if csr_windows_at(cfg, n_tokens, head_dim, dtype) == 1
+            else "windowed")
 
 
-def live_work(plan: DispatchPlan, resident: bool
+def live_work(plan: DispatchPlan
               ) -> dict[str, tuple[jax.Array, jax.Array | int]]:
     """Live work against launched grid slots of the three Dispatch kernels.
 
     ``{"gemm_q_rows", "csr_tiles", "gemm_o_heads"} -> (live, launched)``,
     each summed over every leading axis of the plan (layers, batch,
     heads).  ``live`` is an int32 device scalar; ``launched`` is a static
-    int read from the plan's shapes, which carry its geometry, except on
-    the resident walk, where it is ``live``:
+    int read from the plan's shapes, which carry its geometry, except where
+    the CSR walk launches only live tiles:
 
       * ``gemm_q_rows``: compact row blocks GEMM-Q computes (Σ ``row_cnt``)
         against its ``Cr`` row slots per sample;
       * ``csr_tiles``: (q-block, kv-block) tiles CSR attention computes
         (Σ ``kv_row_cnt`` over live rows) against the tiles it launches:
-        its grid steps ``B·H·Cq·Ckv`` uniform, ``B·S`` bucketed, the
-        per-shard ``B·H·P·Cqs·Ckv`` on a plan-sharded mesh; with
-        ``resident`` (the uniform kernel's resident walk, see
-        :func:`csr_path`) only the live tiles, so ``launched`` is ``live``;
+        ``B·S`` slots bucketed; the uniform kernel's walk, resident or
+        windowed (see :func:`csr_path`), on one device or per shard,
+        launches only the live tiles, so there ``launched`` is ``live``;
       * ``gemm_o_heads``: (row, head) slots GEMM-O reduces (Σ ``head_cnt``)
         against ``B·Cr·H`` uniform, ``B·S`` bucketed.
 
@@ -642,18 +654,18 @@ def live_work(plan: DispatchPlan, resident: bool
     count) take a grid step and are not live work.
     """
     total = lambda a: jnp.sum(a, dtype=jnp.int32)
+
+    def walk(q_cnt, row_cnt):
+        live_rows = jnp.arange(row_cnt.shape[-1]) < q_cnt[..., None]
+        tiles = total(jnp.where(live_rows, row_cnt, 0))
+        return tiles, tiles
+
     if plan.shd_kv_row_ids is not None:
-        live_rows = (jnp.arange(plan.shd_kv_row_cnt.shape[-1])
-                     < plan.shd_q_cnt[..., None])
-        tiles = total(jnp.where(live_rows, plan.shd_kv_row_cnt, 0))
-        csr = tiles, (tiles if resident else plan.shd_kv_row_ids.size)
+        csr = walk(plan.shd_q_cnt, plan.shd_kv_row_cnt)
     elif plan.bkt_kv_cnt is not None:
         csr = total(plan.bkt_kv_cnt), plan.bkt_kv_ids.size
     else:
-        live_rows = (jnp.arange(plan.kv_row_cnt.shape[-1])
-                     < plan.q_cnt[..., None])
-        tiles = total(jnp.where(live_rows, plan.kv_row_cnt, 0))
-        csr = tiles, (tiles if resident else plan.kv_row_ids.size)
+        csr = walk(plan.q_cnt, plan.kv_row_cnt)
     if plan.gmo_head_cnt is not None:
         gmo = total(plan.gmo_head_cnt), plan.gmo_head_ids.size
     else:

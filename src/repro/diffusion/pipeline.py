@@ -21,6 +21,7 @@ loop materializes the whole trace.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 from typing import Any, Optional
@@ -75,6 +76,61 @@ _SAMPLER_CACHE_SIZE = 32
 _SAMPLER_CACHE = LruCache(_SAMPLER_CACHE_SIZE)
 
 
+def _seq_mesh(ecfg: EngineConfig):
+    """``(rules, mesh)`` of a sequence-sharded engine mesh: tokens over
+    ``seq``, batch over ``data``; ``None`` elsewhere."""
+    if ecfg.mesh_sp <= 1 or ecfg.mesh_axis != "seq":
+        return None
+    from repro.distributed.sharding import ShardingRules
+    from repro.launch.mesh import make_engine_mesh
+    return (ShardingRules(dp=("data",), fsdp=(), tp=(), sp=("seq",)),
+            make_engine_mesh(ecfg.mesh_dp, ecfg.mesh_sp))
+
+
+def _token_split(ecfg: EngineConfig):
+    """On a sequence-sharded engine mesh, the block's activation hints
+    (``distributed.ctx.constrain``) split the tokens over the ``seq`` axis
+    and the batch over ``data``, so projections, norms and the MLP run on
+    each chip's own tokens; elsewhere they are off."""
+    seq = _seq_mesh(ecfg)
+    if seq is None:
+        return contextlib.nullcontext()
+    from repro.distributed.ctx import activation_rules
+    return activation_rules(*seq)
+
+
+def _state_shardings(cfg: ArchConfig, ecfg: EngineConfig):
+    """Where a request's engine states live: on a sequence-sharded engine
+    mesh as :func:`repro.models.dit.engine_state_specs` lays them out (the
+    Taylor state split by token over ``seq``); elsewhere ``None``, the
+    default device, as the sampler takes them."""
+    seq = _seq_mesh(ecfg)
+    if seq is None:
+        return None
+    from repro.distributed.sharding import named_sharding_tree
+    rules, mesh = seq
+    return named_sharding_tree(dit.engine_state_specs(cfg, ecfg), mesh,
+                               rules)
+
+
+_INIT_CACHE = LruCache(_SAMPLER_CACHE_SIZE)
+
+
+def _init_states(cfg: ArchConfig, ecfg: EngineConfig, batch: int,
+                 n_tokens: int):
+    """A request's fresh engine states, made in place by one compiled call
+    (one per shape, kept in an LRU memo) rather than op by op on one chip
+    and then moved (:func:`_state_shardings`)."""
+    key = (cfg, ecfg, batch, n_tokens)
+    fn = _INIT_CACHE.get(key)
+    if fn is None:
+        fn = _INIT_CACHE.put(key, jax.jit(
+            functools.partial(dit.init_engine_states, cfg, ecfg, batch,
+                              n_tokens),
+            out_shardings=_state_shardings(cfg, ecfg)))
+    return fn()
+
+
 def build_sampler(cfg: ArchConfig, ecfg: EngineConfig, scfg: SamplerConfig,
                   strategies: tuple, batch: int, n_tokens: int,
                   with_metrics: bool):
@@ -104,7 +160,6 @@ def build_sampler(cfg: ArchConfig, ecfg: EngineConfig, scfg: SamplerConfig,
     57 MB-per-block TaylorSeer state."""
     n_steps = scfg.num_steps
     dt = 1.0 / n_steps
-    resident = csr_path(ecfg, n_tokens, cfg.hd, scfg.dtype) == "resident"
 
     def step_fn(mode: str):
         def f(params, states, xe, te, t, row, i):
@@ -112,7 +167,7 @@ def build_sampler(cfg: ArchConfig, ecfg: EngineConfig, scfg: SamplerConfig,
             if mode == "update":
                 kw = dict(strategies=strategies, strategy_row=row,
                           step_idx=i, num_steps=n_steps)
-            with jax.named_scope(f"fo.{mode}"):
+            with jax.named_scope(f"fo.{mode}"), _token_split(ecfg):
                 return dit.denoise_step(params, cfg, ecfg, states, xe, te, t,
                                         mode=mode, dtype=scfg.dtype, **kw)
         return f
@@ -120,7 +175,7 @@ def build_sampler(cfg: ArchConfig, ecfg: EngineConfig, scfg: SamplerConfig,
     branches = [step_fn("dense"), step_fn("update"), step_fn("dispatch")]
 
     def metrics(states):
-        work = live_work(states.plan, resident)
+        work = live_work(states.plan)
         return (_density_device(states, ecfg, n_tokens),
                 _pair_sparsity_device(states, ecfg, n_tokens),
                 {k: live for k, (live, _) in work.items()},
@@ -187,7 +242,7 @@ def sample(params, cfg: ArchConfig, ecfg: EngineConfig, *,
     n_tokens = nv + text_emb.shape[1]
     n_steps = scfg.num_steps
     with TraceAnnotation("fo.states"):
-        states = dit.init_engine_states(cfg, ecfg, b, n_tokens)
+        states = _init_states(cfg, ecfg, b, n_tokens)
     if patch_embed is None:
         patch_embed = jax.random.normal(jax.random.PRNGKey(7), (pd, cfg.d_model)) * 0.2
 
@@ -226,10 +281,14 @@ def sample(params, cfg: ArchConfig, ecfg: EngineConfig, *,
     if stats is not None:
         stats["executables"] = int(cache_size()) if cache_size else -1
         # Abstract arguments: the thunk pins no device buffer, and the
-        # donated ``states`` are gone by now.
+        # donated ``states`` are gone by now.  An uncommitted argument
+        # (made on the default device) keeps no sharding, as the call
+        # left it free to move beside weights placed on a mesh.
         stats["lower"] = functools.partial(fn.lower, *jax.tree.map(
             lambda a: jax.ShapeDtypeStruct(
-                a.shape, a.dtype, sharding=getattr(a, "sharding", None)),
+                a.shape, a.dtype,
+                sharding=a.sharding if getattr(a, "committed", False)
+                else None),
             args))
         stats["schedule"] = sched
         stats["sampler_cache"] = _SAMPLER_CACHE.stats()

@@ -21,15 +21,18 @@ import jax
 
 from repro.distributed.sharding import ShardingRules, logical_to_physical
 
-_RULES: contextvars.ContextVar[Optional[ShardingRules]] = \
+_RULES: contextvars.ContextVar[Optional[tuple]] = \
     contextvars.ContextVar("sharding_rules", default=None)
 
 __all__ = ["activation_rules", "constrain"]
 
 
 @contextlib.contextmanager
-def activation_rules(rules: Optional[ShardingRules]):
-    tok = _RULES.set(rules)
+def activation_rules(rules: Optional[ShardingRules], mesh=None):
+    """Install ``rules`` for :func:`constrain`; with ``mesh`` the hints are
+    ``NamedSharding``s on it, otherwise they resolve against the mesh of
+    the enclosing context."""
+    tok = _RULES.set(None if rules is None else (rules, mesh))
     try:
         yield
     finally:
@@ -37,8 +40,11 @@ def activation_rules(rules: Optional[ShardingRules]):
 
 
 def constrain(x: jax.Array, *logical: Optional[str]) -> jax.Array:
-    rules = _RULES.get()
-    if rules is None:
+    active = _RULES.get()
+    if active is None:
         return x
+    rules, mesh = active
     spec = logical_to_physical(logical, rules)
+    if mesh is not None:
+        spec = jax.sharding.NamedSharding(mesh, spec)
     return jax.lax.with_sharding_constraint(x, spec)

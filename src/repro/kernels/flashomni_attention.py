@@ -5,24 +5,21 @@ Two variants of the paper's Algorithm 1, adapted to the TPU execution model
 
 ``flashomni_attention_csr``  (default, TPU-native structural skipping)
     The KV reduction runs over per-row CSR column lists for only the
-    ``Cq`` live Q blocks (static capacity); scalar-prefetched index arrays
-    drive the BlockSpec index maps and the in-kernel walk, so skipped
-    tiles are never read.  Cached rows are left untouched via input/output
-    aliasing of the ``o_reuse`` tensor (their forecast value is produced
-    by the ``taylor_reuse`` element-wise kernel, the paper's
-    "alternatively, an elementwise kernel can be invoked").  Two grids,
-    chosen from the shapes (:func:`csr_resident`):
-
-    * resident (K/V of one head fit :data:`RESIDENT_VMEM_BYTES`): grid
-      ``(BH, Cq)``, one step per q-block row.  The head's whole K and V
-      are one VMEM block each, fetched once per head (the pipeline
-      prefetches the next head's behind the current head's rows); the
-      body walks the row's live KV blocks, a few at a time, slicing them
-      out of VMEM.  Padding rows (past ``q_cnt``) do nothing.
-    * streaming (longer sequences): grid ``(BH, Cq, Ckv)``, one 64x64
-      tile per grid step, each DMA'd by its index map.
-
-    Both apply the same online-softmax update per KV block in ascending
+    ``Cq`` live Q blocks (static capacity), so skipped tiles are never
+    read.  Cached rows are left untouched via input/output aliasing of the
+    ``o_reuse`` tensor (their forecast value is produced by the
+    ``taylor_reuse`` element-wise kernel, the paper's "alternatively, an
+    elementwise kernel can be invoked").  Grid ``(BH, Cq)``, one step per
+    q-block row, whose body walks the row's live KV blocks, a few at a
+    time, slicing them out of VMEM; the row's id list arrives in SMEM as
+    its own block, so SMEM use does not grow with ``BH·Cq``.  Where one
+    head's K and V fit :data:`RESIDENT_VMEM_BYTES` they are one VMEM block
+    each, fetched once per head (the resident walk).  Longer sequences
+    cut them into ``W`` windows (:func:`csr_windows`), grid
+    ``(BH, Cq, W)``: each step walks the run of the row's ascending list
+    that lies in its window, carrying the online softmax across windows
+    in VMEM scratch.  Padding rows (past ``q_cnt``) do nothing.  Every
+    path applies the same online-softmax update per KV block in ascending
     id order, so their outputs are bit-identical.
 
 ``flashomni_attention_csr_bucketed``  (occupancy-bucketed two-level grid)
@@ -66,7 +63,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 __all__ = [
     "RESIDENT_VMEM_BYTES",
-    "csr_resident",
+    "csr_windows",
     "flashomni_attention_csr",
     "flashomni_attention_csr_bucketed",
     "flashomni_attention_symbols",
@@ -74,10 +71,13 @@ __all__ = [
 
 _NEG_INF = -1e30
 _LANES = 128  # TPU vreg lane count: m/l kept (bq, 128)-shaped.
-# VMEM the resident CSR walk may give to one head's K and V, double-
-# buffered: under v5e's 16 MiB default scoped limit, with room for the
-# Q/O blocks and the body's f32 temporaries.
-RESIDENT_VMEM_BYTES = 12 * 2**20
+# VMEM the CSR walk may give to one window of a head's K and V, double-
+# buffered, of v5e's 128 MiB a core.  A call whose window passes
+# _SCOPED_KV_BYTES (what fits v5e's 16 MiB default scoped limit beside the
+# Q/O blocks and the body's f32 temporaries) raises its limit by as much.
+RESIDENT_VMEM_BYTES = 48 * 2**20
+_SCOPED_KV_BYTES = 12 * 2**20
+_SCOPED_VMEM_BYTES = 16 * 2**20
 # KV blocks one iteration of the resident walk takes: their scores issue
 # together, so one block's matmuls overlap another's softmax.  At flux
 # width on one v5e a call took 29.3 / 17.0 / 10.4 / 7.3 ms at 1 / 2 / 4 / 8.
@@ -130,49 +130,53 @@ def _finalize(o_ref, acc_ref, l_ref):
     o_ref[0] = _normalize(acc_ref[...], l_ref[...]).astype(o_ref.dtype)
 
 
+def _walk(q, ids_ref, k_ref, v_ref, lo, hi, base, *, scale: float,
+          block_kv: int, group: int):
+    """``fori_loop`` body over the row's slots ``[lo, hi)``, ``group`` at a
+    time: their scores are issued together, the online-softmax updates
+    then run in ascending slot order.  ``base`` is the first KV block of
+    the window ``k_ref`` / ``v_ref`` hold."""
+    def take(live, j0):
+        def update(carry):
+            rows = [pl.ds(pl.multiple_of(
+                (ids_ref[0, 0, j0 + i] - base) * block_kv, block_kv), block_kv)
+                for i in range(live)]
+            scores = [_scores(q, k_ref[0, r, :], scale) for r in rows]
+            for s, r in zip(scores, rows):
+                carry = _online_softmax(s, v_ref[0, r, :], *carry)
+            return carry
+        return update
+
+    def step(g, carry):
+        j0 = lo + g * group
+        live = jnp.clip(hi - j0, 0, group)
+        return jax.lax.switch(live, [take(i, j0) for i in range(group + 1)],
+                              carry)
+    return step
+
+
 def _csr_kernel(
     # scalar prefetch
-    q_ids_ref, q_src_ids_ref, kv_ids_ref, kv_cnt_ref,
+    q_ids_ref, q_src_ids_ref, kv_cnt_ref, q_cnt_ref,
     # inputs
-    q_ref, k_ref, v_ref, o_reuse_ref,   # o_reuse aliased to output (untouched)
+    ids_ref,                            # this row's (1, 1, Ckv) KV ids, SMEM
+    q_ref, k_ref, v_ref, o_reuse_ref,   # k/v: one window (1, window·bkv, d)
     # outputs
     o_ref,
-    # scratch
-    acc_ref, m_ref, l_ref,
-    *,
-    scale: float,
-    ckv: int,
-):
-    c, j = pl.program_id(1), pl.program_id(2)
-    bh = pl.program_id(0)
-
-    @pl.when(j == 0)
-    def _():
-        _init(acc_ref, m_ref, l_ref)
-
-    @pl.when(j < kv_cnt_ref[bh, c])
-    def _():
-        _tile_update(q_ref[0].astype(jnp.float32), k_ref[0], v_ref[0],
-                     acc_ref, m_ref, l_ref, scale)
-
-    @pl.when(j == ckv - 1)
-    def _():
-        _finalize(o_ref, acc_ref, l_ref)
-
-
-def _csr_resident_kernel(
-    # scalar prefetch
-    q_ids_ref, q_src_ids_ref, kv_ids_ref, kv_cnt_ref, q_cnt_ref,
-    # inputs
-    q_ref, k_ref, v_ref, o_reuse_ref,   # k/v: the head's whole (1, N_kv, d)
-    # outputs
-    o_ref,
-    *,
+    # scratch: (m, l, acc) across the windows of a row (windowed walk only)
+    *carry_refs,
     scale: float,
     ckv: int,
     block_kv: int,
+    window: int,
 ):
     bh, c = pl.program_id(0), pl.program_id(1)
+    if carry_refs:     # read at the top level: the interpreter wants it so
+        w, last = pl.program_id(2), pl.num_programs(2) - 1
+    group = min(_WALK_GROUP, ckv)
+    walk = functools.partial(_walk, ids_ref=ids_ref, k_ref=k_ref,
+                             v_ref=v_ref, scale=scale, block_kv=block_kv,
+                             group=group)
 
     # Padding rows repeat the last live row's output block, which stays
     # resident across consecutive steps with the same index: leaving it
@@ -181,44 +185,64 @@ def _csr_resident_kernel(
     def _():
         q = q_ref[0].astype(jnp.float32)
         n = kv_cnt_ref[bh, c]
-
-        def take(live, j0):
-            """The carry after the ``live`` blocks from slot ``j0``: their
-            scores are issued together, the updates then run in order."""
-            def update(carry):
-                rows = [pl.ds(pl.multiple_of(
-                    kv_ids_ref[bh, c * ckv + j0 + i] * block_kv, block_kv),
-                    block_kv) for i in range(live)]
-                scores = [_scores(q, k_ref[0, r, :], scale) for r in rows]
-                for s, r in zip(scores, rows):
-                    carry = _online_softmax(s, v_ref[0, r, :], *carry)
-                return carry
-            return update
-
-        group = min(_WALK_GROUP, ckv)
-
-        def walk(g, carry):
-            j0 = g * group
-            live = jnp.clip(n - j0, 0, group)
-            return jax.lax.switch(
-                live, [take(i, j0) for i in range(group + 1)], carry)
-
-        # (m, l, acc) ride the loop carry rather than VMEM scratch: 18%
-        # less time a call at one block an iteration (flux width, v5e).
         bq, d = q.shape
-        m, l, acc = jax.lax.fori_loop(0, pl.cdiv(ckv, group), walk, (
-            jnp.full((bq, _LANES), _NEG_INF, jnp.float32),
-            jnp.zeros((bq, _LANES), jnp.float32),
-            jnp.zeros((bq, d), jnp.float32)))
-        o_ref[0] = _normalize(acc, l).astype(o_ref.dtype)
+        init = (jnp.full((bq, _LANES), _NEG_INF, jnp.float32),
+                jnp.zeros((bq, _LANES), jnp.float32),
+                jnp.zeros((bq, d), jnp.float32))
+        if not carry_refs:
+            # Resident: (m, l, acc) ride the loop carry rather than VMEM
+            # scratch: 18% less time a call at one block an iteration
+            # (flux width, v5e).
+            m, l, acc = jax.lax.fori_loop(0, pl.cdiv(ckv, group),
+                                          walk(q, lo=0, hi=n, base=0), init)
+            o_ref[0] = _normalize(acc, l).astype(o_ref.dtype)
+            return
+        m_ref, l_ref, acc_ref = carry_refs
+
+        @pl.when(w == 0)
+        def _():
+            m_ref[...], l_ref[...], acc_ref[...] = init
+
+        # The row's ids in this window are a run of its ascending list.
+        lo = _first_at_least(ids_ref, n, w * window, ckv)
+        hi = _first_at_least(ids_ref, n, (w + 1) * window, ckv)
+        m, l, acc = jax.lax.fori_loop(
+            0, pl.cdiv(hi - lo, group), walk(q, lo=lo, hi=hi, base=w * window),
+            (m_ref[...], l_ref[...], acc_ref[...]))
+        m_ref[...], l_ref[...], acc_ref[...] = m, l, acc
+
+        @pl.when(w == last)
+        def _():
+            o_ref[0] = _normalize(acc, l).astype(o_ref.dtype)
 
 
-def csr_resident(n_kv: int, d: int, itemsize: int) -> bool:
-    """Whether the CSR kernel keeps a head's K and V resident in VMEM:
-    both, double-buffered (lanes padded to 128), fit
-    :data:`RESIDENT_VMEM_BYTES`."""
-    lanes = -(-d // _LANES) * _LANES
-    return 4 * n_kv * lanes * itemsize <= RESIDENT_VMEM_BYTES
+def _first_at_least(ids_ref, n, bound, ckv: int):
+    """The first slot of the ascending ``ids_ref[0, 0, :n]`` whose id is at
+    least ``bound`` (``n`` if none): a binary search in SMEM."""
+    def halve(_, lr):
+        lo, hi = lr
+        mid = jnp.minimum((lo + hi) // 2, ckv - 1)
+        right = ids_ref[0, 0, mid] < bound
+        more = lo < hi
+        return (jnp.where(more & right, mid + 1, lo),
+                jnp.where(more & ~right, mid, hi))
+    return jax.lax.fori_loop(0, ckv.bit_length(), halve, (0, n))[0]
+
+
+def _kv_window_bytes(blocks: int, block_kv: int, d: int,
+                     itemsize: int) -> int:
+    """VMEM of a window of ``blocks`` KV blocks of K and V, double-buffered
+    (lanes padded to 128)."""
+    return 4 * blocks * block_kv * (-(-d // _LANES) * _LANES) * itemsize
+
+
+def csr_windows(n_kv: int, d: int, itemsize: int, block_kv: int) -> int:
+    """How many VMEM windows the CSR walk cuts a head's K and V into: the
+    fewest that each fit :data:`RESIDENT_VMEM_BYTES`.  One is the
+    resident walk."""
+    per_window = RESIDENT_VMEM_BYTES // _kv_window_bytes(1, block_kv, d,
+                                                         itemsize)
+    return -(-(n_kv // block_kv) // max(1, per_window))
 
 
 def flashomni_attention_csr(
@@ -243,22 +267,26 @@ def flashomni_attention_csr(
     (the compact-layout fusion GEMM-Q was designed for).  Defaults to
     ``q_ids`` (full-layout Q).
 
-    Runs the resident walk where :func:`csr_resident` holds for K, else
-    the streaming grid.  Padding rows (past ``q_cnt``) must repeat the
-    last live row's ids, as :func:`repro.core.symbols.active_indices`
-    pads them.  Block ``q_ids[bh, 0]`` of a head with ``q_cnt`` 0 is left
-    undefined: callers keep ``o_reuse`` for such heads."""
+    Grid ``(BH, Cq)``, or ``(BH, Cq, W)`` where K and V take
+    :func:`csr_windows` ``W > 1`` windows: each step walks the row's live
+    KV blocks that lie in its window, slicing them out of VMEM.  The
+    row's id list reaches SMEM as its own block each step, so SMEM holds
+    O(Ckv) ids whatever B·H·Cq.  Padding rows (past ``q_cnt``) must
+    repeat the last live row's ids, as
+    :func:`repro.core.symbols.active_indices` pads them.  Block
+    ``q_ids[bh, 0]`` of a head with ``q_cnt`` 0 is left undefined:
+    callers keep ``o_reuse`` for such heads."""
     return _csr_call(q, k, v, o_reuse, q_ids, kv_ids, kv_cnt, q_cnt,
                      block_q=block_q, block_kv=block_kv, scale=scale,
                      interpret=interpret, q_src_ids=q_src_ids,
-                     resident=csr_resident(k.shape[1], k.shape[2],
-                                           k.dtype.itemsize))
+                     windows=csr_windows(k.shape[1], k.shape[2],
+                                         k.dtype.itemsize, block_kv))
 
 
 def _csr_call(q, k, v, o_reuse, q_ids, kv_ids, kv_cnt, q_cnt, *,
               block_q: int, block_kv: int, scale: Optional[float],
               interpret: bool, q_src_ids: Optional[jax.Array],
-              resident: bool) -> jax.Array:
+              windows: int) -> jax.Array:
     bhs, n_q, d = q.shape
     n_kv = k.shape[1]
     assert n_q % block_q == 0 and n_kv % block_kv == 0
@@ -266,51 +294,53 @@ def _csr_call(q, k, v, o_reuse, q_ids, kv_ids, kv_cnt, q_cnt, *,
     cq, ckv = q_ids.shape[1], kv_ids.shape[2]
     scale = (d ** -0.5) if scale is None else scale
     q_src_ids = q_ids if q_src_ids is None else q_src_ids
-    flat_kv = kv_ids.reshape(bhs, cq * ckv)
+    n_blocks = n_kv // block_kv
+    window = -(-n_blocks // windows)                 # KV blocks a window
+    windows = -(-n_blocks // window)
+    kernel = functools.partial(_csr_kernel, scale=scale, ckv=ckv,
+                               block_kv=block_kv, window=window)
+    prefetch = (q_ids, q_src_ids, kv_cnt, q_cnt)
 
-    if resident:
+    if windows == 1:
         grid = (bhs, cq)
-        kernel = functools.partial(_csr_resident_kernel, scale=scale,
-                                   ckv=ckv, block_kv=block_kv)
-        prefetch = (q_ids, q_src_ids, flat_kv, kv_cnt, q_cnt)
         scratch = []
 
-        def q_map(bh, c, q_ids_ref, q_src_ids_ref, *_):
-            return (bh, q_src_ids_ref[bh, c], 0)
-
-        def o_map(bh, c, q_ids_ref, *_):
-            return (bh, q_ids_ref[bh, c], 0)
-
-        kv_spec = pl.BlockSpec((1, n_kv, d), lambda bh, c, *_: (bh, 0, 0))
+        def kv_map(bh, c, *_):
+            return (bh, 0, 0)
     else:
-        grid = (bhs, cq, ckv)
-        kernel = functools.partial(_csr_kernel, scale=scale, ckv=ckv)
-        prefetch = (q_ids, q_src_ids, flat_kv, kv_cnt)
-
-        def q_map(bh, c, j, q_ids_ref, q_src_ids_ref, kv_ids_ref, kv_cnt_ref):
-            return (bh, q_src_ids_ref[bh, c], 0)
-
-        def kv_map(bh, c, j, q_ids_ref, q_src_ids_ref, kv_ids_ref, kv_cnt_ref):
-            # Clamp padded slots to the last live column (re-DMA of a
-            # resident block — Mosaic elides the copy when the index is
-            # unchanged).
-            jj = jnp.maximum(jnp.minimum(j, kv_cnt_ref[bh, c] - 1), 0)
-            return (bh, kv_ids_ref[bh, c * ckv + jj], 0)
-
-        def o_map(bh, c, j, q_ids_ref, q_src_ids_ref, kv_ids_ref, kv_cnt_ref):
-            return (bh, q_ids_ref[bh, c], 0)
-
-        kv_spec = pl.BlockSpec((1, block_kv, d), kv_map)
-        scratch = [pltpu.VMEM((block_q, d), jnp.float32),
+        grid = (bhs, cq, windows)
+        scratch = [pltpu.VMEM((block_q, _LANES), jnp.float32),
                    pltpu.VMEM((block_q, _LANES), jnp.float32),
-                   pltpu.VMEM((block_q, _LANES), jnp.float32)]
+                   pltpu.VMEM((block_q, d), jnp.float32)]
 
+        def kv_map(bh, c, w, q_ids_ref, q_src_ref, kv_cnt_ref, q_cnt_ref):
+            # A padding row stays on the last window: no DMA.
+            return (bh, jnp.where(c < q_cnt_ref[bh], w, windows - 1), 0)
+
+    # The row's list as an (1, 1, Ckv) block: its last two dimensions are
+    # the array's, which Mosaic requires of a block it cannot tile.
+    def ids_map(bh, c, *_):
+        return (bh * cq + c, 0, 0)
+
+    # Index maps take the grid indices, then the four prefetched refs.
+    def q_map(bh, c, *rest):
+        q_ids_ref, q_src_ids_ref = rest[-4:-2]
+        return (bh, q_src_ids_ref[bh, c], 0)
+
+    def o_map(bh, c, *rest):
+        return (bh, rest[-4][bh, c], 0)
+
+    kv_spec = pl.BlockSpec((1, window * block_kv, d), kv_map)
+    kv_bytes = _kv_window_bytes(window, block_kv, d, k.dtype.itemsize)
+    vmem_limit = None if kv_bytes <= _SCOPED_KV_BYTES else \
+        _SCOPED_VMEM_BYTES - _SCOPED_KV_BYTES + kv_bytes
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(prefetch),
             grid=grid,
             in_specs=[
+                pl.BlockSpec((1, 1, ckv), ids_map, memory_space=pltpu.SMEM),
                 pl.BlockSpec((1, block_q, d), q_map),
                 kv_spec,
                 kv_spec,
@@ -321,13 +351,14 @@ def _csr_call(q, k, v, o_reuse, q_ids, kv_ids, kv_cnt, q_cnt, *,
         ),
         out_shape=jax.ShapeDtypeStruct(o_reuse.shape, o_reuse.dtype),
         # NB: alias indices count the scalar-prefetch operands too.
-        input_output_aliases={len(prefetch) + 3: 0},        # o_reuse -> out
+        input_output_aliases={len(prefetch) + 4: 0},        # o_reuse -> out
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",) + ("arbitrary",) * (len(grid) - 1),
+            vmem_limit_bytes=vmem_limit,
         ),
         interpret=interpret,
         name="flashomni_csr_attention",
-    )(*prefetch, q, k, v, o_reuse)
+    )(*prefetch, kv_ids.reshape(bhs * cq, 1, ckv), q, k, v, o_reuse)
 
 
 # ---------------------------------------------------------------------------

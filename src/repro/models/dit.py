@@ -202,12 +202,12 @@ def _block(cfg: ArchConfig, ecfg: EngineConfig, p, state, x, t_emb, *, mode: str
         o, new_state = E.dispatch_layer(attn_p, xa, state, ecfg, n_text=n_text,
                                         heads=cfg.n_heads)
     else:  # "dense": engine off (baseline / training)
-        from repro.core.attention import dense_attention
+        from repro.core.backend import get_backend
         with jax.named_scope("fo.qkv"):
             q, k = E._qk(attn_p, xa, cfg.n_heads, None)
             v = E._project_heads(xa, attn_p.wv, cfg.n_heads)
         with jax.named_scope("fo.attention"):
-            oh = dense_attention(q, k, v)
+            oh = get_backend(ecfg).dense_attention(q, k, v)
         with jax.named_scope("fo.o_proj"):
             o = oh.transpose(0, 2, 1, 3).reshape(*xa.shape[:2], -1) @ attn_p.wo
         new_state = state

@@ -203,9 +203,14 @@ def test_plan_validator_green_per_strategy(strategy):
     assert check_plan(st.plan, cfg, _N) == []
 
 
-def test_plan_validator_green_on_mesh_partition():
+def test_plan_validator_green_on_mesh_partition(monkeypatch):
     """The shd_* partition checks run on ONE device (partition_plan is
-    pure jnp at Update time)."""
+    pure jnp at Update time; Update's attention runs unsharded here, as
+    its per-shard form needs the mesh's devices)."""
+    from repro.core.backend import MeshBackend
+    monkeypatch.setattr(MeshBackend, "dense_attention",
+                        lambda self, q, k, v: self.inner.dense_attention(
+                            q, k, v))
     cfg = _engine_cfg(kv_buckets=1, mesh_dp=1, mesh_sp=2)
     x = jax.random.normal(jax.random.PRNGKey(2), (_B, _N, _DM)) * 0.3
     st0 = init_layer_state(_B, _H, _N, _DM, _DH, cfg)
@@ -502,13 +507,56 @@ def test_full_kv_allgather_is_flagged():
 
 def test_rebuild_every_dispatch_is_flagged():
     """dispatch cost := update cost models an engine that rebuilds the
-    plan every step — the amortization line must fail."""
-    cfg = _matched(_engine_cfg(backend="xla", kv_buckets=1), 2, 2, _N)
-    u = _update_cost(cfg, _N)
-    findings = amortization_findings(
-        "cost-update-amortization", "fixture", u, u,
-        _dense_reference_cost(_N), cfg.mask.interval)
-    assert any(f.rule == "interval-amortization" for f in findings)
+    plan every step — the amortization line must fail, against XLA's
+    dense step on both backends."""
+    for kw in (dict(backend="xla"), dict(backend="pallas", interpret=True)):
+        cfg = _matched(_engine_cfg(kv_buckets=1, **kw), 2, 2, _N)
+        u = _update_cost(cfg, _N)
+        findings = amortization_findings(
+            "cost-update-amortization", "fixture", u, u,
+            _dense_reference_cost(_N), cfg.mask.interval)
+        assert any(f.rule == "interval-amortization" for f in findings), kw
+
+
+def test_kernel_declared_cost_replaces_its_body_walk():
+    """A ``pallas_call`` that declares its cost is costed by it (one FLOP
+    per transcendental, the HBM bytes it declares), not by its body times
+    the grid; the dense attention kernel declares its matmul FLOPs, one
+    ``exp`` per score, Q and O once and K/V once per query tile."""
+    from jax.experimental import pallas as pl
+    from repro.analysis.cost_model import cost_of_jaxpr
+    from repro.kernels.dense_attention import (dense_tiles,
+                                               flashomni_dense_attention)
+
+    def double(x_ref, o_ref):
+        o_ref[...] = x_ref[...] * 2.0
+
+    def call(x, ce):
+        spec = pl.BlockSpec((8, 128), lambda i: (i, 0))
+        return pl.pallas_call(
+            double, grid=(4,), in_specs=[spec], out_specs=spec,
+            out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
+            cost_estimate=ce, interpret=True)(x)
+
+    x = jax.ShapeDtypeStruct((32, 128), jnp.float32)
+    walked = cost_of_jaxpr(jax.make_jaxpr(lambda x: call(x, None))(x))
+    declared = cost_of_jaxpr(jax.make_jaxpr(lambda x: call(
+        x, pl.CostEstimate(flops=7, transcendentals=3,
+                           bytes_accessed=11)))(x))
+    assert (declared.flops, declared.hbm_bytes) == (10.0, 11.0)
+    assert walked.flops >= 32 * 128 and walked.hbm_bytes > 11.0
+
+    bh, n, d = 4, 1200, 128                  # 1,200 keys: padded tiles
+    q = jax.ShapeDtypeStruct((1, bh, n, d), jnp.bfloat16)
+    jx = jax.make_jaxpr(lambda q, k, v: flashomni_dense_attention(
+        q, k, v, interpret=True))(q, q, q)
+    ce = next(e for e in jx.jaxpr.eqns
+              if e.primitive.name == "pallas_call").params["cost_estimate"]
+    bq, bk = dense_tiles(n, n)
+    n_q, n_k = -(-n // bq) * bq, -(-n // bk) * bk
+    assert (ce.flops, ce.transcendentals) == (4 * bh * n * n * d, bh * n * n)
+    assert ce.bytes_accessed == 2 * bh * d * (2 * n_q
+                                              + 2 * (n_q // bq) * n_k)
 
 
 def test_memory_hog_is_flagged():

@@ -66,7 +66,7 @@ def test_attention_csr_with_capacity():
 
 
 # ---------------------------------------------------------------------------
-# CSR attention: resident walk vs streaming grid
+# CSR attention: resident walk vs windowed walk vs one tile at a time
 # ---------------------------------------------------------------------------
 
 def _csr_plan(seed, bh, t, cap_q, cap_kv, p_c=0.6, p_s=0.5):
@@ -81,6 +81,33 @@ def _csr_plan(seed, bh, t, cap_q, cap_kv, p_c=0.6, p_s=0.5):
     return q_ids, q_cnt, kv_ids, kv_cnt
 
 
+def _tile_by_tile(q, k, v, o_reuse, q_ids, kv_ids, kv_cnt, q_cnt, blk,
+                  q_src):
+    """What the CSR kernel computes, one (q-block, kv-block) tile at a time
+    in ascending id order: the kernel's own update functions, jitted per
+    tile as the interpreter runs them."""
+    from repro.kernels import flashomni_attention as fa
+    scale = q.shape[-1] ** -0.5
+    upd = jax.jit(lambda qb, kb, vb, m, l, acc: fa._online_softmax(
+        fa._scores(qb, kb, scale), vb, m, l, acc))
+    out = np.array(o_reuse)
+    for b in range(q.shape[0]):
+        for c in range(int(q_cnt[b])):
+            src = int(q_src[b, c])
+            qb = q[b, src * blk:(src + 1) * blk].astype(jnp.float32)
+            carry = (jnp.full((blk, 128), -1e30, jnp.float32),
+                     jnp.zeros((blk, 128), jnp.float32),
+                     jnp.zeros((blk, q.shape[-1]), jnp.float32))
+            for j in range(int(kv_cnt[b, c])):
+                i = int(kv_ids[b, c, j])
+                carry = upd(qb, k[b, i * blk:(i + 1) * blk],
+                            v[b, i * blk:(i + 1) * blk], *carry)
+            r = int(q_ids[b, c])
+            out[b, r * blk:(r + 1) * blk] = np.asarray(
+                fa._normalize(carry[2], carry[1]).astype(o_reuse.dtype))
+    return out
+
+
 CSR_PATH_CASES = {
     # name: (bh, n, d, blk, cap_q, cap_kv, compact)
     "full_lists": (3, 256, 64, 32, 8, 8, False),
@@ -91,7 +118,10 @@ CSR_PATH_CASES = {
 
 
 @pytest.mark.parametrize("case", CSR_PATH_CASES)
-def test_resident_walk_matches_streaming_bitwise(case):
+def test_windowed_walk_matches_resident_bitwise(case):
+    """K/V cut into 3 windows (the last one short) and into one per block
+    give the resident walk's bits, which are those of an ascending
+    tile-by-tile online softmax."""
     from repro.kernels.flashomni_attention import _csr_call
     bh, n, d, blk, cap_q, cap_kv, compact = CSR_PATH_CASES[case]
     q, k, v, _, _, o_reuse = _attn_inputs(11, bh, n, d, blk, blk)
@@ -105,27 +135,52 @@ def test_resident_walk_matches_streaming_bitwise(case):
                                  q_ids.shape)
         q_src = jnp.minimum(q_src, jnp.maximum(q_cnt[:, None] - 1, 0))
         q = q[:, :cap_q * blk]
-    out = {res: _csr_call(q, k, v, o_reuse, q_ids, kv_ids, kv_cnt, q_cnt,
-                          block_q=blk, block_kv=blk, scale=None,
-                          interpret=True, q_src_ids=q_src, resident=res)
-           for res in (False, True)}
-    np.testing.assert_array_equal(np.asarray(out[True]),
-                                  np.asarray(out[False]))
+    n_blocks = n // blk
+    assert n_blocks % 3                        # the last window is short
+    out = {w: _csr_call(q, k, v, o_reuse, q_ids, kv_ids, kv_cnt, q_cnt,
+                        block_q=blk, block_kv=blk, scale=None,
+                        interpret=True, q_src_ids=q_src, windows=w)
+           for w in (1, 3, n_blocks)}
+    for w in (3, n_blocks):
+        np.testing.assert_array_equal(np.asarray(out[w]), np.asarray(out[1]))
+    np.testing.assert_array_equal(
+        np.asarray(out[1]),
+        _tile_by_tile(q, k, v, o_reuse, q_ids, kv_ids, kv_cnt, q_cnt, blk,
+                      q_ids if q_src is None else q_src))
     # The row with no KV block writes zeros; cached blocks keep o_reuse.
     zero_blk = int(q_ids[1, 0])
     np.testing.assert_array_equal(
-        np.asarray(out[True][1, zero_blk * blk:(zero_blk + 1) * blk]), 0.0)
-    live = np.zeros((bh, n // blk), bool)
+        np.asarray(out[1][1, zero_blk * blk:(zero_blk + 1) * blk]), 0.0)
+    live = np.zeros((bh, n_blocks), bool)
     for b in range(bh):
         live[b, np.asarray(q_ids[b, :int(q_cnt[b])])] = True
     cached = np.repeat(~live, blk, axis=1)
-    np.testing.assert_array_equal(np.asarray(out[True])[cached],
+    np.testing.assert_array_equal(np.asarray(out[1])[cached],
                                   np.asarray(o_reuse)[cached])
+
+
+def test_csr_scalar_prefetch_does_not_grow_with_the_id_lists():
+    """The per-row KV id lists reach SMEM one row per grid step: every
+    scalar-prefetched operand is at most B·H·Cq long, whatever Ckv."""
+    from repro.kernels.flashomni_attention import _csr_call
+    bh, n, d, blk, cap_q = 2, 256, 32, 16, 16
+    q, k, v, _, _, o_reuse = _attn_inputs(3, bh, n, d, blk, blk)
+    for cap_kv, windows in ((4, 1), (16, 1), (16, 4)):
+        q_ids, q_cnt, kv_ids, kv_cnt = _csr_plan(2, bh, n // blk, cap_q,
+                                                 cap_kv)
+        jaxpr = jax.make_jaxpr(lambda *a: _csr_call(
+            *a, block_q=blk, block_kv=blk, scale=None, interpret=True,
+            q_src_ids=None, windows=windows))(
+                q, k, v, o_reuse, q_ids, kv_ids, kv_cnt, q_cnt)
+        (eqn,) = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
+        n_pre = eqn.params["grid_mapping"].num_index_operands
+        sizes = [int(np.prod(x.aval.shape)) for x in eqn.invars[:n_pre]]
+        assert n_pre == 4 and max(sizes) <= bh * cap_q, sizes
 
 
 def test_resident_walk_keeps_o_reuse_on_a_dead_head(monkeypatch):
     """A head with q_cnt 0 runs no row; ``PallasBackend.attention``'s guard
-    returns its ``o_reuse``, and the other heads equal the streaming grid's."""
+    returns its ``o_reuse``, and the other heads equal the windowed walk's."""
     from repro.core import EngineConfig, MaskConfig
     from repro.core.backend import PallasBackend
     from repro.core.plan import build_dispatch_plan
@@ -144,23 +199,58 @@ def test_resident_walk_keeps_o_reuse_on_a_dead_head(monkeypatch):
     attn = lambda: PallasBackend(interpret=True).attention(
         q, k, v, o_reuse, plan, spec)
     resident = attn()
-    monkeypatch.setattr(fa, "csr_resident", lambda *a: False)
-    streaming = attn()
-    np.testing.assert_array_equal(np.asarray(resident), np.asarray(streaming))
+    monkeypatch.setattr(fa, "csr_windows", lambda *a: 3)
+    windowed = attn()
+    np.testing.assert_array_equal(np.asarray(resident), np.asarray(windowed))
     np.testing.assert_array_equal(np.asarray(resident[:, 0]),
                                   np.asarray(o_reuse[:, 0]))
 
 
-@pytest.mark.parametrize("n_kv,d,itemsize,resident", [
-    (4608, 128, 2, True),        # flux width, bf16: 4.72 MB
-    (4608, 128, 4, True),        # flux width, f32: 9.44 MB
-    (33_024, 128, 2, False),     # hunyuan length: 33.8 MB
-    (4608, 64, 4, True),         # lanes pad d 64 to 128
-    (8192, 64, 4, False),        # ... and count: 16.8 MB, not 8.4 MB
+@pytest.mark.parametrize("n_kv,d,itemsize,windows", [
+    (4608, 128, 2, 1),           # flux width, bf16: 4.72 MB
+    (4608, 128, 4, 1),           # flux width, f32: 9.44 MB
+    (33_088, 128, 2, 1),         # hunyuan's per-shard buffer: 33.9 MB
+    (66_048, 128, 2, 2),         # 66K keys on one chip: 67.6 MB
+    (24_576, 64, 4, 1),          # lanes pad d 64 to 128: 50.3 MB ...
+    (24_640, 64, 4, 2),          # ... and count: one block more
 ])
-def test_csr_path_is_chosen_from_the_shapes(n_kv, d, itemsize, resident):
-    from repro.kernels.flashomni_attention import csr_resident
-    assert csr_resident(n_kv, d, itemsize) is resident
+def test_csr_path_is_chosen_from_the_shapes(n_kv, d, itemsize, windows):
+    from repro.kernels.flashomni_attention import csr_windows
+    assert csr_windows(n_kv, d, itemsize, 64) == windows
+
+
+# ---------------------------------------------------------------------------
+# Dense attention (Update and engine-off steps): blockwise kernel vs XLA
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("bh,n_q,n_kv,d,dtype,tol", [
+    (2, 128, 128, 64, jnp.float32, 1e-5),
+    (3, 80, 80, 32, jnp.float32, 1e-5),       # 16 text + 64 image tokens
+    (2, 200, 328, 64, jnp.float32, 1e-5),     # no tile divides either
+    (2, 256, 384, 128, jnp.bfloat16, 3e-2),
+])
+def test_dense_attention_kernel_matches_xla(bh, n_q, n_kv, d, dtype, tol):
+    from repro.core.attention import dense_attention
+    from repro.kernels.dense_attention import flashomni_dense_attention
+    ks = jax.random.split(jax.random.PRNGKey(n_q + n_kv), 3)
+    q = (2 * jax.random.normal(ks[0], (bh, n_q, d))).astype(dtype)
+    k = (2 * jax.random.normal(ks[1], (bh, n_kv, d))).astype(dtype)
+    v = jax.random.normal(ks[2], (bh, n_kv, d)).astype(dtype)
+    got = flashomni_dense_attention(q, k, v, interpret=True)
+    assert got.shape == q.shape and got.dtype == dtype
+    want = dense_attention(*(a.astype(jnp.float32) for a in (q, k, v)))
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want), atol=tol, rtol=tol)
+
+
+def test_dense_attention_tiles_follow_the_lengths():
+    """Query tiles divide the served lengths; keys split evenly into
+    tiles of at most 1,536 columns (the 33K shard's 33,024 keys padded to
+    22 of them and masked); a length no tile divides is padded."""
+    from repro.kernels.dense_attention import dense_tiles
+    assert dense_tiles(4608, 4608) == (768, 1536)
+    assert dense_tiles(33_024 // 4, 33_024) == (688, 1536)
+    assert dense_tiles(200, 80) == (208, 128)
 
 
 GEMM_SWEEP = [

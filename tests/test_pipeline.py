@@ -78,3 +78,23 @@ def test_more_aggressive_interval_is_less_faithful(setup):
                      text_emb=text, x0=x0, scfg=scfg)
         psnrs[interval] = _psnr(out, dense)
     assert psnrs[2] >= psnrs[6] - 1.0, psnrs      # small slack for noise
+
+
+def test_fresh_states_come_from_one_compiled_call(setup):
+    """A request's engine states are made by one memoized compiled call:
+    the eager construction's values, left uncommitted on the default
+    device (where the sampler takes them off a mesh), and the second
+    request reuses the first's call."""
+    from repro.diffusion import pipeline
+    cfg, _, x0, text = setup
+    ecfg = _ecfg(interval=7)                  # a shape no other test made
+    n = x0.shape[1] + text.shape[1]
+    before = len(pipeline._INIT_CACHE)
+    first = pipeline._init_states(cfg, ecfg, 1, n)
+    again = pipeline._init_states(cfg, ecfg, 1, n)
+    assert len(pipeline._INIT_CACHE) == before + 1
+    eager = dit.init_engine_states(cfg, ecfg, 1, n)
+    for a, b, c in zip(*(jax.tree.leaves(t) for t in (first, again, eager))):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(c))
+        np.testing.assert_array_equal(np.asarray(b), np.asarray(c))
+        assert not a.committed and a.dtype == c.dtype

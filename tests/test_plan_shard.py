@@ -147,9 +147,15 @@ def test_partition_invariants():
                 assert (np.diff(u) > 0).all()
 
 
-def test_plan_from_state_rebuild_bit_exact():
-    """ISSUE 7: ``plan_from_state`` rebuilds the shd_* partition fields
-    bit-exactly from the packed symbols under a mesh config."""
+def test_plan_from_state_rebuild_bit_exact(monkeypatch):
+    """``plan_from_state`` rebuilds the shd_* partition fields
+    bit-exactly from the packed symbols under a mesh config.  On one
+    device Update's attention runs unsharded: its per-shard form needs the
+    mesh's devices, and the plan is what this test checks."""
+    from repro.core.backend import MeshBackend
+    monkeypatch.setattr(MeshBackend, "dense_attention",
+                        lambda self, q, k, v: self.inner.dense_attention(
+                            q, k, v))
     cfgm = EngineConfig(mask=MASK, mesh_dp=1, mesh_sp=2)
     key = jax.random.PRNGKey(3)
     ks = jax.random.split(key, 5)

@@ -1,7 +1,7 @@
 """Compile rehearsals for a TPU v5e, with no chip attached.
 
-The three kernels of the served Dispatch path and one full-width
-Dispatch step are compiled for a *described* v5e
+The kernels of the served path and one full-width Dispatch step are
+compiled for a *described* v5e
 (``jax.experimental.topologies``).  Nothing executes, so these tests say
 nothing about results or speed; they catch what interpret mode cannot: a
 tile the Mosaic compiler refuses, a kernel over its VMEM/SMEM budget, a
@@ -10,20 +10,29 @@ step that does not lower with the kernels in it.
 Shapes are flux-mmdit's published ones at batch 1: 512 text + 4,096
 image tokens, d_model 3072, 24 heads x 128, tiles 64/64, pool 128.
 
+The last test compiles the HunyuanVideo cell's whole sampler at the
+paper's 33K tokens (``chipbench/configs/hunyuan-video.json``: 256 text +
+32,768 video tokens, its depth, the ``(1, 4)`` sequence mesh) for the
+described 2x2: it must hold the blockwise dense kernel and the CSR kernel,
+build no score array of a shard's rows against every key, keep the CSR
+kernel's SMEM under v5e's 1 MiB and fit a chip's 15.75 GiB.
+
 The topology is described inside a module-scoped fixture and never at
 import, in a ``skipif`` or in ``parametrize``: only the worker that runs
-this file loads the TPU library.  The persistent compilation cache is
-turned off around these compiles, since what they write could not be
-read back without a chip.
+this file loads the TPU library, so every test that needs it is here.
+The persistent compilation cache is turned off around these compiles,
+since what they write could not be read back without a chip.
 """
 
 from __future__ import annotations
 
 import dataclasses
 import functools
+import json
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -37,6 +46,8 @@ ECFG = EngineConfig(backend="pallas", interpret=False)  # MaskConfig tiles
 SPEC = ECFG.caps(N_TOKENS)                              # cap_q 54, cap_kv 66
 POOL = ECFG.mask.pool
 CAP_ROWS = ECFG.cap_q_cmp(N_TOKENS)                     # live pool rows (27)
+HBM_BYTES = 15.75 * 2 ** 30        # what the compiler lets a v5e program use
+SMEM_BYTES = 2 ** 20               # v5e's scalar memory a core
 
 
 @pytest.fixture(scope="module")
@@ -81,9 +92,9 @@ def _csr_shapes(n_kv):
 def test_csr_attention_compiles_at_flux_width(one_chip):
     """At flux width (B·H 24, N 4,608, d 128) the entry takes the resident
     walk: K and V of a head held in VMEM, one grid step per q-block row."""
-    from repro.kernels.flashomni_attention import (csr_resident,
+    from repro.kernels.flashomni_attention import (csr_windows,
                                                    flashomni_attention_csr)
-    assert csr_resident(N_TOKENS, DH, 2)
+    assert csr_windows(N_TOKENS, DH, 2, SPEC.block_kv) == 1
     fn = functools.partial(flashomni_attention_csr, block_q=SPEC.block_q,
                            block_kv=SPEC.block_kv)
     compiled = _compile(
@@ -95,14 +106,18 @@ def test_csr_attention_compiles_at_flux_width(one_chip):
     assert "flashomni_csr_attention" in text
 
 
-def test_streaming_csr_attention_compiles_past_the_resident_budget(one_chip):
-    """Past the VMEM budget (hunyuan's 33,024 tokens) the entry keeps the
-    streaming grid, one K/V tile DMA'd per grid step."""
-    from repro.kernels.flashomni_attention import (csr_resident,
-                                                   flashomni_attention_csr)
+@pytest.mark.parametrize("budget,windows", [(None, 1), (12 * 2**20, 3)])
+def test_windowed_csr_attention_compiles_past_the_resident_budget(
+        one_chip, monkeypatch, budget, windows):
+    """At hunyuan's 33,024 keys a head's K and V (33.8 MB double-buffered)
+    stay resident under the default budget, the call raising its scoped
+    VMEM limit; under a 12 MiB budget the walk cuts them into windows."""
+    from repro.kernels import flashomni_attention as fa
+    if budget is not None:
+        monkeypatch.setattr(fa, "RESIDENT_VMEM_BYTES", budget)
     n_kv = 33_024
-    assert not csr_resident(n_kv, DH, 2)
-    fn = functools.partial(flashomni_attention_csr, block_q=SPEC.block_q,
+    assert fa.csr_windows(n_kv, DH, 2, SPEC.block_kv) == windows
+    fn = functools.partial(fa.flashomni_attention_csr, block_q=SPEC.block_q,
                            block_kv=SPEC.block_kv)
     compiled = _compile(
         lambda q, k, v, o, qi, ki, kc, qc, qs: fn(q, k, v, o, qi, ki, kc, qc,
@@ -111,6 +126,17 @@ def test_streaming_csr_attention_compiles_past_the_resident_budget(one_chip):
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     assert "flashomni_csr_attention" in text
+
+
+def test_dense_attention_compiles_at_flux_width(one_chip):
+    """Update's blockwise attention at flux width: 768-row query tiles
+    against 1,536-key tiles, under its own name."""
+    from repro.kernels.dense_attention import flashomni_dense_attention
+    shape = ((1, H, N_TOKENS, DH), jnp.bfloat16)
+    text = _compile(flashomni_dense_attention, one_chip, shape, shape,
+                    shape).as_text()
+    assert "tpu_custom_call" in text
+    assert "flashomni_dense_attention" in text
 
 
 def test_gemm_q_compiles_at_flux_width(one_chip):
@@ -168,3 +194,109 @@ def test_full_width_dispatch_step_compiles_with_kernels(one_chip):
     for name in ("flashomni_csr_attention", "flashomni_gemm_q",
                  "flashomni_gemm_o"):
         assert name in text, name
+
+
+
+
+def _eqns(jaxpr):
+    """Every equation of ``jaxpr`` and of the jaxprs inside it."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for param in eqn.params.values():
+            for sub in param if isinstance(param, (list, tuple)) else [param]:
+                sub = getattr(sub, "jaxpr", sub)
+                if isinstance(sub, jax.extend.core.Jaxpr):
+                    yield from _eqns(sub)
+
+
+def _smem_bytes(eqn) -> int:
+    """Scalar-prefetched operands plus SMEM blocks, double-buffered."""
+    gm = eqn.params["grid_mapping"]
+    total = sum(v.aval.size * v.aval.dtype.itemsize
+                for v in eqn.invars[:gm.num_index_operands])
+    for bm in gm.block_mappings:
+        block = bm.transformed_block_aval
+        if str(getattr(block, "memory_space", "")) == "smem":
+            total += 2 * block.size * block.dtype.itemsize
+    return total
+
+
+def test_hunyuan_sampler_at_33k_compiles_for_four_chips(topo, monkeypatch):
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    from chipbench import bench as B
+    from chipbench import model as M
+    from repro.core.engine import resolve_schedule
+    from repro.diffusion import pipeline
+    from repro.launch import mesh as launch_mesh
+    from repro.models import dit
+
+    cell = B.find_cell(B.load_benchmark(), "hunyuan.sparse.33k")
+    spec, mix = cell["model"], cell["mix"]
+    s = spec["sizes"]
+    nt, nv = s["n_text_tokens"], s["n_image_tokens"]
+    n, sp = nt + nv, spec["mesh"][1]
+    assert (n, tuple(spec["mesh"]), cell["chips"]) == (33_024, (1, 4), 4)
+    mesh = Mesh(np.asarray(topo.devices[:4]).reshape(spec["mesh"]),
+                ("data", "seq"))
+    monkeypatch.setattr(launch_mesh, "make_engine_mesh",
+                        lambda dp=1, sp=1: mesh)
+    cfg, ecfg = B.program_configs(spec, mix)
+    assert ecfg.backend == "pallas" and not ecfg.interpret
+    sched = resolve_schedule(ecfg, mix["steps"], cfg.n_layers)
+    whole = NamedSharding(mesh, PartitionSpec())
+
+    def place(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=whole), tree)
+
+    params = place(jax.eval_shape(lambda: M._params(
+        s, cfg.n_layers, spec["weights"]["qk_norm_gain"], jnp.bfloat16,
+        jax.random.key(0))))
+    # The states as a request makes them: split by the engine's specs.
+    states = jax.tree.map(
+        lambda a, sh: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh),
+        jax.eval_shape(lambda: dit.init_engine_states(cfg, ecfg, 1, n)),
+        pipeline._state_shardings(cfg, ecfg))
+    derivs = states.taylor.derivs
+    assert derivs.sharding.spec[3] == "seq", derivs.sharding
+    args = (params, place(jax.ShapeDtypeStruct((1, nv, s["patch_dim"]),
+                                               jnp.float32)),
+            states, place(jax.ShapeDtypeStruct((1, nt, s["d_model"]),
+                                               jnp.float32)),
+            place(jax.ShapeDtypeStruct((s["patch_dim"], s["d_model"]),
+                                       jnp.float32)),
+            place(sched.mode), place(sched.strategy_ids))
+    sampler = pipeline.build_sampler(
+        cfg, ecfg, pipeline.SamplerConfig(num_steps=mix["steps"],
+                                          dtype=jnp.bfloat16),
+        sched.strategies, 1, n, True)
+    traced = sampler.trace(*args)
+
+    # No scores of a shard's rows against every key (the XLA dense path's
+    # (B, H, N/4, N) per shard, or (B, H, N, N) whole) anywhere.
+    eqns = list(_eqns(traced.jaxpr.jaxpr))
+    square = {v.aval.shape for e in eqns for v in (*e.invars, *e.outvars)
+              if sum(d in (n, n // sp)
+                     for d in getattr(v.aval, "shape", ())) > 1}
+    assert not square, square
+
+    kernels = {}
+    for e in eqns:
+        if e.primitive.name == "pallas_call":
+            kernels.setdefault(e.params["name"], []).append(e)
+    csr = kernels["flashomni_csr_attention"]
+    assert "flashomni_dense_attention" in kernels
+    smem = max(_smem_bytes(e) for e in csr)
+    assert smem < SMEM_BYTES / 4, smem
+
+    compiled = traced.lower().compile()       # Mosaic checks VMEM limits
+    text = compiled.as_text()
+    for name in ("flashomni_dense_attention", "flashomni_csr_attention",
+                 "flashomni_gemm_q", "flashomni_gemm_o"):
+        assert name in text, name
+    m = compiled.memory_analysis()
+    per_chip = (m.argument_size_in_bytes + m.temp_size_in_bytes
+                + m.output_size_in_bytes - m.alias_size_in_bytes)
+    print(json.dumps({"per_chip_bytes": per_chip, "csr_smem_bytes": smem}))
+    assert per_chip <= 0.9 * HBM_BYTES, per_chip / 2 ** 30

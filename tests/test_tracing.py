@@ -14,7 +14,8 @@ from repro.configs.registry import get_smoke
 from repro.core.engine import EngineConfig
 from repro.core.masks import MaskConfig
 from repro.core.plan import (bucket_geometry, bucket_grid_slots,
-                             build_dispatch_plan, csr_path, live_work)
+                             build_dispatch_plan, csr_path, csr_windows_at,
+                             live_work)
 from repro.core.symbols import pack_bits, unpack_bits
 from repro.diffusion.pipeline import SamplerConfig, sample
 from repro.models import dit
@@ -120,10 +121,10 @@ def _masks(seed: int, b: int, h: int, t: int):
             unpack_bits(s_s, t * t).reshape(b, h, t, t))
 
 
-def _recount(m_c, m_s, plan, spec, heads, resident=False):
+def _recount(m_c, m_s, plan, spec, heads):
     """The counters recounted in NumPy: each tile, row and head a kernel
     would compute that the masks hold live, and the slots its grid walks
-    (the resident CSR walk launches only the live tiles)."""
+    (the uniform CSR walk launches only the live tiles)."""
     m_c, m_s = np.asarray(m_c), np.asarray(m_s)
     p = jax.tree.map(np.asarray, plan.widen())
     b, cr = p.row_ids.shape
@@ -146,11 +147,9 @@ def _recount(m_c, m_s, plan, spec, heads, resident=False):
         gmo_grid = b * bucket_grid_slots(
             bucket_geometry(cr, heads, 1, spec.kv_buckets))
     else:
-        csr_grid = b * heads * spec.cap_q * spec.cap_kv
-        gmo_grid = b * cr * heads
-    if resident and spec.kv_buckets == 1:
         csr_grid = sum(int(p.kv_row_cnt[bi, hh, c]) for bi in range(b)
                        for hh in range(heads) for c in range(p.q_cnt[bi, hh]))
+        gmo_grid = b * cr * heads
     return {"gemm_q_rows": (rows, b * cr), "csr_tiles": (tiles, csr_grid),
             "gemm_o_heads": (heads_live, gmo_grid)}
 
@@ -168,7 +167,7 @@ def test_live_work_matches_a_recount_from_the_masks(kv_buckets):
         layers.append((m_c, m_s, plan))
         assert (plan.bkt_kv_cnt is not None) == (kv_buckets > 1)
         want = _recount(m_c, m_s, plan, spec, h)
-        got = {k: (int(v), n) for k, (v, n) in live_work(plan, False).items()}
+        got = {k: (int(v), n) for k, (v, n) in live_work(plan).items()}
         assert got == want
         if kv_buckets == 1:
             # No capacity truncates here: every live tile, row and (row,
@@ -181,44 +180,46 @@ def test_live_work_matches_a_recount_from_the_masks(kv_buckets):
             assert 0 < live <= launched
     # Over stacked layers, the counters are the layers' sums.
     stacked = jax.tree.map(lambda *a: jnp.stack(a), *(p for *_, p in layers))
-    each = [live_work(p, False) for *_, p in layers]
-    for k, (live, launched) in live_work(stacked, False).items():
+    each = [live_work(p) for *_, p in layers]
+    for k, (live, launched) in live_work(stacked).items():
         assert int(live) == sum(int(w[k][0]) for w in each)
         assert launched == sum(w[k][1] for w in each)
 
 
 @pytest.mark.parametrize("mesh_sp", [1, 2])
 def test_resident_walk_launches_only_live_tiles(mesh_sp):
-    """The resident CSR walk has no grid step per tile: it launches the
-    live rows' tiles and nothing else, on one device and per shard."""
+    """The CSR walk has no grid step per tile: resident or cut into
+    windows, it launches the live rows' tiles and nothing else, on one
+    device and per shard."""
     b, h, t = 2, 3, 8
     m_c, m_s = _masks(7, b, h, t)
     cfg = _ecfg(mesh_sp=mesh_sp)
     plan = build_dispatch_plan(m_c, m_s, cfg, 16 * t)
-    res, grid = live_work(plan, True), live_work(plan, False)
-    assert int(res["csr_tiles"][0]) == int(res["csr_tiles"][1]) \
-        == int(grid["csr_tiles"][0]) < grid["csr_tiles"][1]
+    work = live_work(plan)
+    assert 0 < int(work["csr_tiles"][0]) == int(work["csr_tiles"][1])
     if mesh_sp == 1:
-        want = _recount(m_c, m_s, plan, cfg.caps(16 * t), h, resident=True)
-        assert int(res["csr_tiles"][1]) == want["csr_tiles"][1]
-    for k in ("gemm_q_rows", "gemm_o_heads"):
-        assert (int(res[k][0]), res[k][1]) == (int(grid[k][0]), grid[k][1])
+        want = _recount(m_c, m_s, plan, cfg.caps(16 * t), h)
+        assert int(work["csr_tiles"][1]) == want["csr_tiles"][1]
 
 
 def test_csr_path_follows_the_shapes():
-    """flux width is resident (bf16 and f32 K/V), hunyuan length streams,
-    the sequence-sharded mesh reads its per-shard buffer, and kv_buckets
-    > 1 takes the bucketed grid."""
+    """flux width and hunyuan length are resident (bf16 and f32 K/V),
+    twice hunyuan's length walks windows, the sequence-sharded mesh reads
+    its per-shard buffer, and kv_buckets > 1 takes the bucketed grid."""
     from repro.configs.registry import get_config
     flux = get_config("flux-mmdit")
     ecfg = EngineConfig()
     assert csr_path(ecfg, 4608, flux.hd, jnp.bfloat16) == "resident"
     assert csr_path(ecfg, 4608, flux.hd, jnp.float32) == "resident"
-    assert csr_path(ecfg, 33_024, flux.hd, jnp.bfloat16) == "streaming"
+    assert csr_path(ecfg, 33_024, flux.hd, jnp.bfloat16) == "resident"
+    assert csr_path(ecfg, 66_048, flux.hd, jnp.bfloat16) == "windowed"
+    assert csr_windows_at(ecfg, 66_048, flux.hd, jnp.bfloat16) == 2
     assert csr_path(EngineConfig(mesh_sp=4), 4608, flux.hd,
                     jnp.bfloat16) == "resident"
     assert csr_path(EngineConfig(mesh_sp=4), 33_024, flux.hd,
-                    jnp.bfloat16) == "streaming"
+                    jnp.bfloat16) == "resident"
+    assert csr_path(EngineConfig(mesh_sp=4), 264_192, flux.hd,
+                    jnp.bfloat16) == "windowed"
     assert csr_path(EngineConfig(kv_buckets=3), 4608, flux.hd,
                     jnp.bfloat16) == "bucketed"
 
@@ -230,10 +231,9 @@ def test_live_work_on_a_plan_sharded_mesh():
     m_c, m_s = _masks(5, b, h, t)
     one = build_dispatch_plan(m_c, m_s, _ecfg(), 16 * t)
     mesh = build_dispatch_plan(m_c, m_s, _ecfg(mesh_sp=2), 16 * t)
-    work, work1 = live_work(mesh, False), live_work(one, False)
-    assert int(work["csr_tiles"][0]) == int(work1["csr_tiles"][0])
-    assert work["csr_tiles"][1] == mesh.shd_kv_row_ids.size == (
-        b * h * 2 * mesh.shd_q_ids.shape[-1] * mesh.shd_kv_row_ids.shape[-1])
+    work, work1 = live_work(mesh), live_work(one)
+    assert int(work["csr_tiles"][0]) == int(work1["csr_tiles"][0]) \
+        == work["csr_tiles"][1]
     for k in ("gemm_q_rows", "gemm_o_heads"):
         assert (int(work[k][0]), work[k][1]) == (int(work1[k][0]), work1[k][1])
 
