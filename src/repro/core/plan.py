@@ -238,7 +238,7 @@ def bucket_layout(q_ids, q_cnt, q_slots, kv_row_ids, kv_row_cnt,
     bkt_kv_cnt = jnp.minimum(g(cnt), w_pos)
     # Scatter the bucket truncation back into the per-row counts so the
     # uniform kernel and the XLA per-row CSR path see the SAME truncated
-    # lists — bucketed vs uniform stays bit-identical, no carve-outs.
+    # lists — bucketed and uniform compute the same tiles, no carve-outs.
     new_cnt = jnp.put_along_axis(jnp.zeros_like(flat2(cnt)), order,
                                  bkt_kv_cnt, axis=-1,
                                  inplace=False).reshape(b_, h_, cq)
@@ -632,13 +632,14 @@ def csr_path(cfg, n_tokens: int, head_dim: int, dtype) -> str:
 
 def live_work(plan: DispatchPlan
               ) -> dict[str, tuple[jax.Array, jax.Array | int]]:
-    """Live work against launched grid slots of the three Dispatch kernels.
+    """Live work against launched grid slots of the three Dispatch kernels,
+    and how full the CSR walk's fused updates run.
 
-    ``{"gemm_q_rows", "csr_tiles", "gemm_o_heads"} -> (live, launched)``,
-    each summed over every leading axis of the plan (layers, batch,
-    heads).  ``live`` is an int32 device scalar; ``launched`` is a static
-    int read from the plan's shapes, which carry its geometry, except where
-    the CSR walk launches only live tiles:
+    ``{"gemm_q_rows", "csr_tiles", "csr_groups", "gemm_o_heads"} ->
+    (live, launched)``, each summed over every leading axis of the plan
+    (layers, batch, heads).  ``live`` is an int32 device scalar;
+    ``launched`` is a static int read from the plan's shapes, which carry
+    its geometry, except where it counts the CSR walk:
 
       * ``gemm_q_rows``: compact row blocks GEMM-Q computes (Σ ``row_cnt``)
         against its ``Cr`` row slots per sample;
@@ -647,31 +648,41 @@ def live_work(plan: DispatchPlan
         ``B·S`` slots bucketed; the uniform kernel's walk, resident or
         windowed (see :func:`csr_path`), on one device or per shard,
         launches only the live tiles, so there ``launched`` is ``live``;
+      * ``csr_groups``: the same live tiles against ``G`` times the
+        online-softmax updates the walk runs, ``G`` the blocks one update
+        fuses (``kernels.flashomni_attention.walk_group``): a row of ``n``
+        live blocks takes ``ceil(n / G)`` updates.  The windowed walk
+        restarts a group at each window cut, so this counts the resident
+        walk's groups.  The bucketed grid updates one tile a slot, so
+        there it equals ``csr_tiles``;
       * ``gemm_o_heads``: (row, head) slots GEMM-O reduces (Σ ``head_cnt``)
         against ``B·Cr·H`` uniform, ``B·S`` bucketed.
 
     Padding slots (rows past ``q_cnt`` / ``row_cnt``, columns past a row's
     count) take a grid step and are not live work.
     """
+    from repro.kernels.flashomni_attention import walk_group
     total = lambda a: jnp.sum(a, dtype=jnp.int32)
 
-    def walk(q_cnt, row_cnt):
+    def walk(q_cnt, row_cnt, row_ids):
         live_rows = jnp.arange(row_cnt.shape[-1]) < q_cnt[..., None]
-        tiles = total(jnp.where(live_rows, row_cnt, 0))
-        return tiles, tiles
+        cnt = jnp.where(live_rows, row_cnt, 0)
+        tiles, g = total(cnt), walk_group(row_ids.shape[-1])
+        return (tiles, tiles), (tiles, g * total(-(-cnt // g)))
 
     if plan.shd_kv_row_ids is not None:
-        csr = walk(plan.shd_q_cnt, plan.shd_kv_row_cnt)
+        csr, groups = walk(plan.shd_q_cnt, plan.shd_kv_row_cnt,
+                           plan.shd_kv_row_ids)
     elif plan.bkt_kv_cnt is not None:
-        csr = total(plan.bkt_kv_cnt), plan.bkt_kv_ids.size
+        csr = groups = total(plan.bkt_kv_cnt), plan.bkt_kv_ids.size
     else:
-        csr = walk(plan.q_cnt, plan.kv_row_cnt)
+        csr, groups = walk(plan.q_cnt, plan.kv_row_cnt, plan.kv_row_ids)
     if plan.gmo_head_cnt is not None:
         gmo = total(plan.gmo_head_cnt), plan.gmo_head_ids.size
     else:
         gmo = total(plan.head_cnt), plan.head_ids.size
     return {"gemm_q_rows": (total(plan.row_cnt), plan.row_ids.size),
-            "csr_tiles": csr, "gemm_o_heads": gmo}
+            "csr_tiles": csr, "csr_groups": groups, "gemm_o_heads": gmo}
 
 
 def empty_plan_like(batch: int, heads: int, n_tokens: int, cfg) -> DispatchPlan:

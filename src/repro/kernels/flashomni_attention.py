@@ -18,9 +18,12 @@ Two variants of the paper's Algorithm 1, adapted to the TPU execution model
     cut them into ``W`` windows (:func:`csr_windows`), grid
     ``(BH, Cq, W)``: each step walks the run of the row's ascending list
     that lies in its window, carrying the online softmax across windows
-    in VMEM scratch.  Padding rows (past ``q_cnt``) do nothing.  Every
-    path applies the same online-softmax update per KV block in ascending
-    id order, so their outputs are bit-identical.
+    in VMEM scratch.  Padding rows (past ``q_cnt``) do nothing.  The walk
+    makes one online-softmax update per group of up to ``G``
+    (:func:`walk_group`) live KV blocks in ascending id order: one
+    ``(bq, g·bkv)`` score block, one max/sum chain and rescale, one
+    ``g·bkv``-deep P·V.  A window cut restarts the grouping, so the
+    resident and windowed walks sum in different orders.
 
 ``flashomni_attention_csr_bucketed``  (occupancy-bucketed two-level grid)
     The uniform CSR grid still pads every live row's reduction to the
@@ -36,8 +39,9 @@ Two variants of the paper's Algorithm 1, adapted to the TPU execution model
     compile-time constant of the geometry, scalar-prefetched like the
     index lists; the uniform kernel is exactly the ``n_buckets = 1``
     degenerate case of this layout.  Bucket truncation is folded back
-    into ``kv_row_cnt`` at plan build, so bucketed and uniform outputs
-    are BIT-IDENTICAL (same ascending-id flash accumulation order).
+    into ``kv_row_cnt`` at plan build, so the bucketed and uniform
+    kernels compute the same tiles; the bucketed one updates one tile a
+    grid step.
 
 ``flashomni_attention_symbols``  (paper-faithful predication)
     The grid covers every ``(i, j)`` tile; each program decodes the packed
@@ -46,8 +50,12 @@ Two variants of the paper's Algorithm 1, adapted to the TPU execution model
     branch (Algorithm 1 lines 5–10).  Demonstrates symbol-decode fidelity;
     DMA traffic is NOT reduced (documented GPU→TPU non-transfer).
 
-All validate against :func:`repro.kernels.ref.attention_ref` in
-``interpret=True`` mode; on real v5e the CSR variants are the serving path.
+Numerical contract: each path is exact against its own ordered
+reference (the same updates over the same tiles in the same order, in
+f32), and the paths agree with each other, and with
+:func:`repro.kernels.ref.attention_ref`, to f32 rounding.  All validate
+in ``interpret=True`` mode; on real v5e the CSR variants are the serving
+path.
 """
 
 from __future__ import annotations
@@ -78,10 +86,11 @@ _LANES = 128  # TPU vreg lane count: m/l kept (bq, 128)-shaped.
 RESIDENT_VMEM_BYTES = 48 * 2**20
 _SCOPED_KV_BYTES = 12 * 2**20
 _SCOPED_VMEM_BYTES = 16 * 2**20
-# KV blocks one iteration of the resident walk takes: their scores issue
-# together, so one block's matmuls overlap another's softmax.  At flux
-# width on one v5e a call took 29.3 / 17.0 / 10.4 / 7.3 ms at 1 / 2 / 4 / 8.
-_WALK_GROUP = 8
+# KV blocks one online-softmax update of the CSR walk takes (the last
+# group of a row takes the rest).  A call on one v5e took 4.51 / 2.77 /
+# 2.04 ms at flux's shape and 48.5 / 26.3 / 17.3 ms at a hunyuan shard's
+# with 4 / 8 / 16, against 3.85 / 40.1 ms for one update per block.
+_WALK_GROUP = 16
 
 
 # ---------------------------------------------------------------------------
@@ -130,29 +139,55 @@ def _finalize(o_ref, acc_ref, l_ref):
     o_ref[0] = _normalize(acc_ref[...], l_ref[...]).astype(o_ref.dtype)
 
 
-def _walk(q, ids_ref, k_ref, v_ref, lo, hi, base, *, scale: float,
-          block_kv: int, group: int):
-    """``fori_loop`` body over the row's slots ``[lo, hi)``, ``group`` at a
-    time: their scores are issued together, the online-softmax updates
-    then run in ascending slot order.  ``base`` is the first KV block of
-    the window ``k_ref`` / ``v_ref`` hold."""
-    def take(live, j0):
-        def update(carry):
-            rows = [pl.ds(pl.multiple_of(
-                (ids_ref[0, 0, j0 + i] - base) * block_kv, block_kv), block_kv)
-                for i in range(live)]
-            scores = [_scores(q, k_ref[0, r, :], scale) for r in rows]
-            for s, r in zip(scores, rows):
-                carry = _online_softmax(s, v_ref[0, r, :], *carry)
-            return carry
-        return update
+def _group_update(q, ks, vs, carry, scale: float):
+    """One online-softmax update over a group of KV blocks: the row's
+    scores against all of them as one ``(bq, g·bkv)`` block, one max/sum
+    chain and one rescale of ``acc``, then one ``g·bkv``-deep P·V.  With
+    one block it is the per-tile update."""
+    k = ks[0] if len(ks) == 1 else jnp.concatenate(ks)
+    v = vs[0] if len(vs) == 1 else jnp.concatenate(vs)
+    return _online_softmax(_scores(q, k, scale), v, *carry)
 
-    def step(g, carry):
-        j0 = lo + g * group
-        live = jnp.clip(hi - j0, 0, group)
-        return jax.lax.switch(live, [take(i, j0) for i in range(group + 1)],
-                              carry)
-    return step
+
+def walk_group(ckv: int) -> int:
+    """KV blocks the CSR walk fuses into one update, for rows of ``ckv``
+    slots."""
+    return min(_WALK_GROUP, ckv)
+
+
+def _walk(q, carry, ids_ref, k_ref, v_ref, lo, hi, base, *, scale: float,
+          block_kv: int, group: int):
+    """The online softmax ``carry`` ``(m, l, acc)`` over the row's slots
+    ``[lo, hi)``, in ascending order, ``group`` KV blocks an update
+    (:func:`_group_update`): full groups straight-line in a loop, then the
+    last, partial one by its width (:func:`_switch`).  ``base`` is the
+    first KV block of the window ``k_ref`` / ``v_ref`` hold."""
+    def update(j0, width, carry):
+        rows = [pl.ds(pl.multiple_of(
+            (ids_ref[0, 0, j0 + i] - base) * block_kv, block_kv), block_kv)
+            for i in range(width)]
+        return _group_update(q, [k_ref[0, r, :] for r in rows],
+                             [v_ref[0, r, :] for r in rows], carry, scale)
+
+    full = (hi - lo) // group
+    carry = jax.lax.fori_loop(
+        0, full, lambda g, c: update(lo + g * group, group, c), carry)
+    j0 = lo + full * group
+    return _switch(hi - j0, [lambda c: c] + [functools.partial(update, j0, w)
+                                             for w in range(1, group)], carry)
+
+
+def _switch(index, branches, carry):
+    """``branches[index](carry)`` through a balanced tree of ``lax.cond``:
+    ``lax.switch`` nests one conditional per case, and Mosaic's layout pass
+    overflows its stack on the 16 of a group of 16."""
+    if len(branches) == 1:
+        return branches[0](carry)
+    mid = len(branches) // 2
+    return jax.lax.cond(index < mid,
+                        lambda c: _switch(index, branches[:mid], c),
+                        lambda c: _switch(index - mid, branches[mid:], c),
+                        carry)
 
 
 def _csr_kernel(
@@ -173,10 +208,9 @@ def _csr_kernel(
     bh, c = pl.program_id(0), pl.program_id(1)
     if carry_refs:     # read at the top level: the interpreter wants it so
         w, last = pl.program_id(2), pl.num_programs(2) - 1
-    group = min(_WALK_GROUP, ckv)
     walk = functools.partial(_walk, ids_ref=ids_ref, k_ref=k_ref,
                              v_ref=v_ref, scale=scale, block_kv=block_kv,
-                             group=group)
+                             group=walk_group(ckv))
 
     # Padding rows repeat the last live row's output block, which stays
     # resident across consecutive steps with the same index: leaving it
@@ -193,8 +227,7 @@ def _csr_kernel(
             # Resident: (m, l, acc) ride the loop carry rather than VMEM
             # scratch: 18% less time a call at one block an iteration
             # (flux width, v5e).
-            m, l, acc = jax.lax.fori_loop(0, pl.cdiv(ckv, group),
-                                          walk(q, lo=0, hi=n, base=0), init)
+            m, l, acc = walk(q, init, lo=0, hi=n, base=0)
             o_ref[0] = _normalize(acc, l).astype(o_ref.dtype)
             return
         m_ref, l_ref, acc_ref = carry_refs
@@ -206,9 +239,8 @@ def _csr_kernel(
         # The row's ids in this window are a run of its ascending list.
         lo = _first_at_least(ids_ref, n, w * window, ckv)
         hi = _first_at_least(ids_ref, n, (w + 1) * window, ckv)
-        m, l, acc = jax.lax.fori_loop(
-            0, pl.cdiv(hi - lo, group), walk(q, lo=lo, hi=hi, base=w * window),
-            (m_ref[...], l_ref[...], acc_ref[...]))
+        m, l, acc = walk(q, (m_ref[...], l_ref[...], acc_ref[...]),
+                         lo=lo, hi=hi, base=w * window)
         m_ref[...], l_ref[...], acc_ref[...] = m, l, acc
 
         @pl.when(w == last)

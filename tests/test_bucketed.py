@@ -3,10 +3,12 @@
   * static bucket geometry: halving widths, equal slot area per bucket,
     rows partition exactly, slot total ≤ 0.5× the uniform grid at B = 3;
   * interpret-mode BIT parity: the bucketed two-level-grid kernel equals
-    the uniform CSR kernel fed the same (bucket-truncated) per-row counts
-    — the PR-4 shared-truncation invariant extended to buckets, no
-    carve-outs — on skewed/bimodal plans including the adversarial one
-    full-capacity row among empties;
+    one online-softmax update a tile, in ascending id order, over the
+    (bucket-truncated) per-row counts it shares with the uniform CSR
+    kernel — the PR-4 shared-truncation invariant extended to buckets, no
+    carve-outs — and agrees with the uniform kernel, which fuses its
+    updates by groups, to f32 rounding; on skewed/bimodal plans including
+    the adversarial one full-capacity row among empties;
   * oracle parity: on plans where no bucket truncates, the bucketed
     kernel matches ``masked_block_attention`` within 1e-6;
   * XLA parity: ``XlaBackend`` consumes the bucketed plan's scattered-back
@@ -28,6 +30,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from _csr_oracle import _tile_by_tile
 from repro.configs.registry import get_smoke
 from repro.core import (AttnParams, EngineConfig, MaskConfig, dispatch_layer,
                         init_layer_state, plan_from_state, update_layer)
@@ -102,8 +105,10 @@ def _qkvo(seed, b, h, n, dh):
 
 
 def _parity(m_c, m_s, *, seed=0, n=256, dh=32):
-    """Bucketed kernel vs uniform kernel (shared truncated counts, BIT
-    equal) vs XLA on the bucketed plan (allclose)."""
+    """Bucketed kernel vs one tile an update in ascending order (BIT
+    equal) vs the uniform kernel on the shared truncated counts (to f32
+    rounding: it fuses its walk's updates by groups) vs XLA on the
+    bucketed plan (allclose)."""
     b, h, t = m_c.shape
     cfg_b, cfg_u = _cfgs()
     q, k, v, o_reuse = _qkvo(seed, b, h, n, dh)
@@ -112,12 +117,18 @@ def _parity(m_c, m_s, *, seed=0, n=256, dh=32):
     spec_b, spec_u = cfg_b.caps(n), cfg_u.caps(n)
     pb = PallasBackend(interpret=True)
     out_bkt = pb.attention(q, k, v, o_reuse, plan_b, spec_b)
-    # Same truncated per-row counts through the UNIFORM kernel: the
-    # shared-truncation invariant makes the two layouts bit-identical.
-    out_uni = pb.attention(q, k, v, o_reuse,
-                           plan_u._replace(kv_row_cnt=plan_b.kv_row_cnt),
-                           spec_u)
-    np.testing.assert_array_equal(np.asarray(out_bkt), np.asarray(out_uni))
+    # Same truncated per-row counts through the UNIFORM layout: the
+    # shared-truncation invariant makes the two compute the same tiles.
+    shared = plan_u._replace(kv_row_cnt=plan_b.kv_row_cnt).widen()
+    flat = lambda a: np.asarray(a).reshape(b * h, *a.shape[2:])
+    want = _tile_by_tile(flat(q), flat(k), flat(v), flat(o_reuse),
+                         flat(shared.q_ids), flat(shared.kv_row_ids),
+                         flat(shared.kv_row_cnt), flat(shared.q_cnt),
+                         spec_u.block_q, flat(shared.q_ids))
+    np.testing.assert_array_equal(flat(out_bkt), want)
+    out_uni = pb.attention(q, k, v, o_reuse, shared, spec_u)
+    np.testing.assert_allclose(np.asarray(out_bkt), np.asarray(out_uni),
+                               atol=2e-5, rtol=2e-5)
     out_xla = XlaBackend().attention(q, k, v, o_reuse, plan_b, spec_b)
     np.testing.assert_allclose(np.asarray(out_bkt), np.asarray(out_xla),
                                atol=2e-5, rtol=2e-5)
@@ -164,8 +175,9 @@ def test_bucketed_adversarial_one_full_row_oracle():
 
 def test_bucketed_truncation_is_shared():
     """Overloaded wide rows DO truncate (more full rows than wide slots);
-    the truncated counts are scattered back so uniform-kernel and XLA
-    parity still hold bit-for-bit / within tolerance."""
+    the truncated counts are scattered back so the tile-by-tile parity
+    still holds bit-for-bit and the uniform-kernel and XLA parity within
+    tolerance."""
     b, h, t = 1, 4, 8
     ks = jax.random.split(jax.random.PRNGKey(9), 1)
     m_s = jax.random.bernoulli(ks[0], 0.9, (b, h, t, t))

@@ -8,8 +8,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from _csr_oracle import _group_by_group, _tile_by_tile
 from repro.core.symbols import active_indices
 from repro.kernels import ops, ref
+from repro.kernels.flashomni_attention import _WALK_GROUP
 
 
 def _attn_inputs(key, bh, n, d, bq, bk, p_c=0.6, p_s=0.7, dtype=jnp.float32):
@@ -66,7 +68,7 @@ def test_attention_csr_with_capacity():
 
 
 # ---------------------------------------------------------------------------
-# CSR attention: resident walk vs windowed walk vs one tile at a time
+# CSR attention: resident walk vs windowed walk vs their grouped updates
 # ---------------------------------------------------------------------------
 
 def _csr_plan(seed, bh, t, cap_q, cap_kv, p_c=0.6, p_s=0.5):
@@ -81,48 +83,24 @@ def _csr_plan(seed, bh, t, cap_q, cap_kv, p_c=0.6, p_s=0.5):
     return q_ids, q_cnt, kv_ids, kv_cnt
 
 
-def _tile_by_tile(q, k, v, o_reuse, q_ids, kv_ids, kv_cnt, q_cnt, blk,
-                  q_src):
-    """What the CSR kernel computes, one (q-block, kv-block) tile at a time
-    in ascending id order: the kernel's own update functions, jitted per
-    tile as the interpreter runs them."""
-    from repro.kernels import flashomni_attention as fa
-    scale = q.shape[-1] ** -0.5
-    upd = jax.jit(lambda qb, kb, vb, m, l, acc: fa._online_softmax(
-        fa._scores(qb, kb, scale), vb, m, l, acc))
-    out = np.array(o_reuse)
-    for b in range(q.shape[0]):
-        for c in range(int(q_cnt[b])):
-            src = int(q_src[b, c])
-            qb = q[b, src * blk:(src + 1) * blk].astype(jnp.float32)
-            carry = (jnp.full((blk, 128), -1e30, jnp.float32),
-                     jnp.zeros((blk, 128), jnp.float32),
-                     jnp.zeros((blk, q.shape[-1]), jnp.float32))
-            for j in range(int(kv_cnt[b, c])):
-                i = int(kv_ids[b, c, j])
-                carry = upd(qb, k[b, i * blk:(i + 1) * blk],
-                            v[b, i * blk:(i + 1) * blk], *carry)
-            r = int(q_ids[b, c])
-            out[b, r * blk:(r + 1) * blk] = np.asarray(
-                fa._normalize(carry[2], carry[1]).astype(o_reuse.dtype))
-    return out
-
-
 CSR_PATH_CASES = {
     # name: (bh, n, d, blk, cap_q, cap_kv, compact)
     "full_lists": (3, 256, 64, 32, 8, 8, False),
     "cap_kv_truncates": (3, 256, 64, 32, 8, 3, False),
     "padding_rows": (3, 256, 32, 16, 16, 16, False),
     "compact_q": (3, 256, 64, 32, 8, 5, True),
+    "rows_past_one_group": (2, 640, 32, 16, 28, 40, False),
 }
 
 
 @pytest.mark.parametrize("case", CSR_PATH_CASES)
 def test_windowed_walk_matches_resident_bitwise(case):
-    """K/V cut into 3 windows (the last one short) and into one per block
-    give the resident walk's bits, which are those of an ascending
-    tile-by-tile online softmax."""
-    from repro.kernels.flashomni_attention import _csr_call
+    """The resident walk and K/V cut into 3 windows (the last one short)
+    or one per block each give the bits of their own grouped updates
+    (``walk_group`` blocks an update, restarting at each window cut), and
+    agree with each other to f32 rounding.  One window per block is one
+    tile an update: the bucketed kernel's order."""
+    from repro.kernels.flashomni_attention import _csr_call, walk_group
     bh, n, d, blk, cap_q, cap_kv, compact = CSR_PATH_CASES[case]
     q, k, v, _, _, o_reuse = _attn_inputs(11, bh, n, d, blk, blk)
     q_ids, q_cnt, kv_ids, kv_cnt = _csr_plan(5, bh, n // blk, cap_q, cap_kv)
@@ -141,12 +119,18 @@ def test_windowed_walk_matches_resident_bitwise(case):
                         block_q=blk, block_kv=blk, scale=None,
                         interpret=True, q_src_ids=q_src, windows=w)
            for w in (1, 3, n_blocks)}
+    lists = (q, k, v, o_reuse, q_ids, kv_ids, kv_cnt, q_cnt, blk,
+             q_ids if q_src is None else q_src)
+    for w in (1, 3, n_blocks):
+        np.testing.assert_array_equal(
+            np.asarray(out[w]),
+            _group_by_group(*lists, group=walk_group(cap_kv),
+                            window=-(-n_blocks // w)))
     for w in (3, n_blocks):
-        np.testing.assert_array_equal(np.asarray(out[w]), np.asarray(out[1]))
-    np.testing.assert_array_equal(
-        np.asarray(out[1]),
-        _tile_by_tile(q, k, v, o_reuse, q_ids, kv_ids, kv_cnt, q_cnt, blk,
-                      q_ids if q_src is None else q_src))
+        np.testing.assert_allclose(np.asarray(out[w]), np.asarray(out[1]),
+                                   atol=2e-5, rtol=2e-5)
+    np.testing.assert_array_equal(np.asarray(out[n_blocks]),
+                                  _tile_by_tile(*lists))
     # The row with no KV block writes zeros; cached blocks keep o_reuse.
     zero_blk = int(q_ids[1, 0])
     np.testing.assert_array_equal(
@@ -157,6 +141,60 @@ def test_windowed_walk_matches_resident_bitwise(case):
     cached = np.repeat(~live, blk, axis=1)
     np.testing.assert_array_equal(np.asarray(out[1])[cached],
                                   np.asarray(o_reuse)[cached])
+
+
+@pytest.mark.parametrize("tail", range(_WALK_GROUP))
+def test_walk_last_group_takes_the_rows_remainder(tail):
+    """Rows of ``G + tail`` and ``tail`` live KV blocks (``G`` the walk
+    group): full groups, then one update of the remainder's width, bit for
+    bit as the grouped updates and within 2e-5 of the XLA oracle.  At
+    ``tail`` 0 the second row is live with no KV block and writes zeros;
+    padding rows past ``q_cnt`` write nothing."""
+    from repro.kernels.flashomni_attention import _csr_call, walk_group
+    g = _WALK_GROUP
+    bh, blk, d, t, cap_q = 2, 16, 32, 2 * g, 4
+    n = t * blk
+    assert walk_group(t) == g
+    q, k, v, _, _, o_reuse = _attn_inputs(13 + tail, bh, n, d, blk, blk)
+    rng = np.random.default_rng(tail)
+    counts = [[g + tail, tail], [2 * g - 1 - tail]]
+    q_ids = np.zeros((bh, cap_q), np.int32)
+    q_cnt = np.array([len(c) for c in counts], np.int32)
+    kv_ids = np.zeros((bh, cap_q, t), np.int32)
+    kv_cnt = np.zeros((bh, cap_q), np.int32)
+    m_c = np.zeros((bh, t), bool)
+    m_s = np.zeros((bh, t, t), bool)
+    for b, rows in enumerate(counts):
+        q_ids[b] = np.sort(rng.choice(t, cap_q, replace=False))
+        q_ids[b, len(rows):] = q_ids[b, len(rows) - 1]   # padding rows
+        for c, cnt in enumerate(rows):
+            ids = np.sort(rng.choice(t, cnt, replace=False))
+            kv_ids[b, c, :cnt] = ids
+            kv_ids[b, c, cnt:] = ids[-1] if cnt else 0
+            kv_cnt[b, c] = cnt
+            m_c[b, q_ids[b, c]] = True
+            m_s[b, q_ids[b, c], ids] = True
+        kv_ids[b, len(rows):] = kv_ids[b, len(rows) - 1]
+        kv_cnt[b, len(rows):] = kv_cnt[b, len(rows) - 1]
+    plan = [jnp.asarray(a) for a in (q_ids, kv_ids, kv_cnt, q_cnt)]
+    got = np.asarray(_csr_call(q, k, v, o_reuse, *plan, block_q=blk,
+                               block_kv=blk, scale=None, interpret=True,
+                               q_src_ids=None, windows=1))
+    np.testing.assert_array_equal(
+        got, _group_by_group(q, k, v, o_reuse, *plan, blk, plan[0],
+                             group=g))
+    want = np.asarray(ref.attention_ref(
+        q, k, v, jnp.asarray(m_c), jnp.asarray(m_s), o_reuse,
+        block_q=blk, block_kv=blk))
+    empty = np.zeros((bh, n), bool)            # live rows with no KV block
+    for b, rows in enumerate(counts):
+        for c, cnt in enumerate(rows):
+            if not cnt:
+                empty[b, q_ids[b, c] * blk:(q_ids[b, c] + 1) * blk] = True
+    assert empty.any() == (tail == 0)
+    np.testing.assert_array_equal(got[empty], 0.0)
+    np.testing.assert_allclose(got[~empty], want[~empty],
+                               atol=2e-5, rtol=2e-5)
 
 
 def test_csr_scalar_prefetch_does_not_grow_with_the_id_lists():
@@ -180,7 +218,8 @@ def test_csr_scalar_prefetch_does_not_grow_with_the_id_lists():
 
 def test_resident_walk_keeps_o_reuse_on_a_dead_head(monkeypatch):
     """A head with q_cnt 0 runs no row; ``PallasBackend.attention``'s guard
-    returns its ``o_reuse``, and the other heads equal the windowed walk's."""
+    returns its ``o_reuse``, and the other heads agree with the windowed
+    walk's to f32 rounding (its groups restart at the window cuts)."""
     from repro.core import EngineConfig, MaskConfig
     from repro.core.backend import PallasBackend
     from repro.core.plan import build_dispatch_plan
@@ -201,7 +240,8 @@ def test_resident_walk_keeps_o_reuse_on_a_dead_head(monkeypatch):
     resident = attn()
     monkeypatch.setattr(fa, "csr_windows", lambda *a: 3)
     windowed = attn()
-    np.testing.assert_array_equal(np.asarray(resident), np.asarray(windowed))
+    np.testing.assert_allclose(np.asarray(resident), np.asarray(windowed),
+                               atol=2e-5, rtol=2e-5)
     np.testing.assert_array_equal(np.asarray(resident[:, 0]),
                                   np.asarray(o_reuse[:, 0]))
 

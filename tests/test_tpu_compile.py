@@ -128,6 +128,52 @@ def test_windowed_csr_attention_compiles_past_the_resident_budget(
     assert "flashomni_csr_attention" in text
 
 
+# The CSR call of one shard of the hunyuan cell: 129 of the shard's
+# q-block rows, each listing up to 466 of the 517 KV blocks (33,088 keys)
+# its exchange gathers.
+_HUNYUAN_SHARD = (((H, 33_024, DH), jnp.bfloat16),
+                  ((H, 33_088, DH), jnp.bfloat16),
+                  ((H, 33_088, DH), jnp.bfloat16),
+                  ((H, 8_256, DH), jnp.bfloat16),
+                  ((H, 129), jnp.int32), ((H, 129, 466), jnp.int32),
+                  ((H, 129), jnp.int32), ((H,), jnp.int32),
+                  ((H, 129), jnp.int32))
+
+
+@pytest.mark.parametrize("cell", ["flux", "hunyuan_shard"])
+def test_grouped_csr_walk_lowers_every_group_width(one_chip, cell):
+    """The resident walk's grouped update lowers for a v5e at flux's shape
+    (4,608 keys) and at a hunyuan shard's (33,088 keys): one score block
+    of each width 1..G blocks, G in the loop over full groups and each
+    width below G in the last group's live-count branch.  Mosaic refuses
+    a body past the call's scoped VMEM limit, so the compile checks VMEM;
+    the limit is the resident window's, not raised for the group."""
+    from repro.kernels import flashomni_attention as fa
+    shapes = _csr_shapes(N_TOKENS) if cell == "flux" else _HUNYUAN_SHARD
+    (_, n_kv, _), ckv = shapes[1][0], shapes[5][0][2]
+    assert fa.csr_windows(n_kv, DH, 2, SPEC.block_kv) == 1
+    fn = functools.partial(fa.flashomni_attention_csr, block_q=SPEC.block_q,
+                           block_kv=SPEC.block_kv)
+    args = [jax.ShapeDtypeStruct(sh, dt, sharding=one_chip)
+            for sh, dt in shapes]
+    traced = jax.jit(
+        lambda q, k, v, o, qi, ki, kc, qc, qs: fn(q, k, v, o, qi, ki, kc, qc,
+                                                  q_src_ids=qs)).trace(*args)
+    (call,) = [e for e in _eqns(traced.jaxpr.jaxpr)
+               if e.primitive.name == "pallas_call"]
+    widths = {e.invars[1].aval.shape[0] // SPEC.block_kv
+              for e in _eqns(call.params["jaxpr"])
+              if e.primitive.name == "dot_general"
+              and e.params["dimension_numbers"][0] == ((1,), (1,))}
+    assert widths == set(range(1, fa.walk_group(ckv) + 1)), widths
+    kv_bytes = fa._kv_window_bytes(n_kv // SPEC.block_kv, SPEC.block_kv, DH, 2)
+    limit = call.params["compiler_params"]["mosaic_tpu"].vmem_limit_bytes
+    assert limit == (None if kv_bytes <= 12 * 2**20
+                     else 4 * 2**20 + kv_bytes), limit
+    text = traced.lower().compile().as_text()
+    assert "flashomni_csr_attention" in text
+
+
 def test_dense_attention_compiles_at_flux_width(one_chip):
     """Update's blockwise attention at flux width: 768-row query tiles
     against 1,536-key tiles, under its own name."""

@@ -18,6 +18,7 @@ from repro.core.plan import (bucket_geometry, bucket_grid_slots,
                              live_work)
 from repro.core.symbols import pack_bits, unpack_bits
 from repro.diffusion.pipeline import SamplerConfig, sample
+from repro.kernels.flashomni_attention import walk_group
 from repro.models import dit
 
 
@@ -124,7 +125,8 @@ def _masks(seed: int, b: int, h: int, t: int):
 def _recount(m_c, m_s, plan, spec, heads):
     """The counters recounted in NumPy: each tile, row and head a kernel
     would compute that the masks hold live, and the slots its grid walks
-    (the uniform CSR walk launches only the live tiles)."""
+    (the uniform CSR walk launches only the live tiles, ``ceil(n / G)``
+    updates of ``G`` blocks a row of ``n``)."""
     m_c, m_s = np.asarray(m_c), np.asarray(m_s)
     p = jax.tree.map(np.asarray, plan.widen())
     b, cr = p.row_ids.shape
@@ -146,11 +148,16 @@ def _recount(m_c, m_s, plan, spec, heads):
             bucket_geometry(spec.cap_q, spec.cap_kv, heads, spec.kv_buckets))
         gmo_grid = b * bucket_grid_slots(
             bucket_geometry(cr, heads, 1, spec.kv_buckets))
+        groups = csr_grid
     else:
-        csr_grid = sum(int(p.kv_row_cnt[bi, hh, c]) for bi in range(b)
-                       for hh in range(heads) for c in range(p.q_cnt[bi, hh]))
+        counts = [int(p.kv_row_cnt[bi, hh, c]) for bi in range(b)
+                  for hh in range(heads) for c in range(p.q_cnt[bi, hh])]
+        csr_grid = sum(counts)
+        g = walk_group(p.kv_row_ids.shape[-1])
+        groups = g * sum(-(-n // g) for n in counts)
         gmo_grid = b * cr * heads
     return {"gemm_q_rows": (rows, b * cr), "csr_tiles": (tiles, csr_grid),
+            "csr_groups": (tiles, groups),
             "gemm_o_heads": (heads_live, gmo_grid)}
 
 
@@ -238,6 +245,32 @@ def test_live_work_on_a_plan_sharded_mesh():
         assert (int(work[k][0]), work[k][1]) == (int(work1[k][0]), work1[k][1])
 
 
+@pytest.mark.parametrize("mesh_sp", [1, 2])
+def test_csr_groups_count_the_walks_updates(mesh_sp):
+    """Four live rows of 16, 9, 8 and 1 KV blocks (34 tiles), two on each
+    shard of the (1, 2) mesh: ``ceil(n / G)`` updates a row, counted as
+    ``G`` tiles each, by hand for the walk groups tried on the chip."""
+    t, rows, counts = 16, (0, 5, 9, 14), (16, 9, 8, 1)
+    m_c = np.zeros((1, 1, t), bool)
+    m_s = np.zeros((1, 1, t, t), bool)
+    for r, n in zip(rows, counts):
+        m_c[0, 0, r] = True
+        m_s[0, 0, r, t - n:] = True
+    plan = build_dispatch_plan(jnp.asarray(m_c), jnp.asarray(m_s),
+                               _ecfg(mesh_sp=mesh_sp), 16 * t)
+    if mesh_sp == 1:
+        assert np.asarray(plan.kv_row_cnt)[0, 0, :4].tolist() == [16, 9, 8, 1]
+    else:
+        assert sorted(np.asarray(plan.shd_kv_row_cnt)[
+            np.arange(plan.shd_kv_row_cnt.shape[-1])
+            < np.asarray(plan.shd_q_cnt)[..., None]].tolist()) == [1, 8, 9, 16]
+    g = walk_group(t)
+    by_hand = {4: 4 * (4 + 3 + 2 + 1), 8: 8 * (2 + 2 + 1 + 1),
+               16: 16 * (1 + 1 + 1 + 1)}
+    live, launched = live_work(plan)["csr_groups"]
+    assert (int(live), int(launched)) == (34, by_hand[g])
+
+
 def test_step_counters_ride_the_trace_and_leave_outputs_alone(model):
     ecfg = _ecfg(backend="xla")
     trace: list = []
@@ -248,7 +281,7 @@ def test_step_counters_ride_the_trace_and_leave_outputs_alone(model):
     for st in trace:
         assert {"step", "kind", "density", "pair_sparsity"} <= set(st)
         assert set(st["live"]) == set(st["grid"]) == {
-            "gemm_q_rows", "csr_tiles", "gemm_o_heads"}
+            "gemm_q_rows", "csr_tiles", "csr_groups", "gemm_o_heads"}
         for k, live in st["live"].items():
             assert 0 <= live <= st["grid"][k]
     dispatch = [st for st in trace if st["kind"] == "dispatch"]
